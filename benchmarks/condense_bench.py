@@ -62,6 +62,8 @@ def main(argv=None):
     ap.add_argument("--k", type=int, default=32)
     ap.add_argument("--out", default=str(OUT_DIR / "condense.json"))
     args = ap.parse_args(argv)
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     sizes = tuple(int(s) for s in args.sizes.split(","))
 
     rng = np.random.default_rng(0)

@@ -93,6 +93,8 @@ def main(argv=None):
                     help="compile at lower-n and check per-step collective "
                          "counts (slow: spawns a 256-device subprocess)")
     args = ap.parse_args(argv)
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     n, p = args.n, args.procs
     kstar = int(math.sqrt(n / p))
 

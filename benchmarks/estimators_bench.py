@@ -199,6 +199,8 @@ def main(argv=None):
                     help="also time forward+backward via "
                          "jit(value_and_grad(logdet)) per method")
     args = ap.parse_args(argv)
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
 
     if args.sizes:
         sizes = tuple(int(s) for s in args.sizes.split(","))

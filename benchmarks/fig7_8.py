@@ -96,7 +96,7 @@ def measured_speedups(table3_csv: Path):
 _SCALING_CODE = """
 import json, time
 import numpy as np
-from repro._compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.core.engine import EngineConfig, build_mesh
 
 P, N, iters = {P}, {N}, {iters}
@@ -181,6 +181,8 @@ def main(argv=None):
     ap.add_argument("--measured-procs", default="1,2,4,8")
     ap.add_argument("--iters", type=int, default=3)
     args = ap.parse_args(argv)
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     sizes = [int(x) for x in args.sizes.split(",")]
     procs = [int(x) for x in args.procs.split(",")]
 

@@ -63,6 +63,8 @@ def main(argv=None):
     ap.add_argument("--n", type=int, default=256)
     ap.add_argument("--procs", default="4,8")
     args = ap.parse_args(argv)
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     rows = []
     for p in [int(x) for x in args.procs.split(",")]:
         out = json.loads(run_with_devices(CHILD.format(N=args.n), p))

@@ -208,6 +208,8 @@ def main(argv=None):
     ap.add_argument("--out", default=str(OUT_DIR / "kernels.json"))
     ap.add_argument("--skip-roofline", action="store_true")
     args = ap.parse_args(argv)
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
 
     rows = [] if args.skip_roofline else roofline(args.m)
 

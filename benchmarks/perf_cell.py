@@ -99,6 +99,8 @@ def main(argv=None):
     ap.add_argument("--variant", default="base")
     ap.add_argument("--scan", action="store_true")
     args = ap.parse_args(argv)
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     rec = lower_variant(args.arch, args.shape, args.variant,
                         scan_layers=args.scan)
     slim = {k: rec[k] for k in

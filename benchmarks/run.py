@@ -26,6 +26,8 @@ def main(argv=None):
                     help="comma list: table3,fig7_8,fig9,kernels,"
                          "estimators,roofline")
     args = ap.parse_args(argv)
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     only = set(args.only.split(",")) if args.only else None
 
     def want(name):

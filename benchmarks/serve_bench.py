@@ -138,6 +138,8 @@ def main(argv=None):
     ap.add_argument("--modes", default="naive,bucketed,batched")
     ap.add_argument("--out", default=str(OUT_DIR / "serve.json"))
     args = ap.parse_args(argv)
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
 
     import jax
     jax.config.update("jax_enable_x64", True)

@@ -68,6 +68,8 @@ def main(argv=None):
     ap.add_argument("--sizes", default="")
     ap.add_argument("--procs", default="")
     args = ap.parse_args(argv)
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     if args.full:
         sizes = [1000, 2000, 3000, 4000, 5000, 6000, 7000, 8000]
         procs = [1, 2, 4, 8, 16, 32, 64, 128]

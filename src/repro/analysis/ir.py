@@ -89,6 +89,10 @@ _HLO_TARGET_RE = re.compile(r'custom_call_target="([^"]*)"')
 _MLIR_TENSOR_RE = re.compile(r"tensor<([^<>]*(?:<[^<>]*>)?[^<>]*)>")
 _MLIR_DEF_RE = re.compile(r"^\s*(%[\w#.\-]+(?::\d+)?)\s*=\s*"
                           r'(?:"([\w.]+)"|([\w.]+))')
+# a custom call with no results (host callbacks lower to one) has no
+# `%x =` prefix; it still has to land in the table
+_MLIR_VOID_CALL_RE = re.compile(r'^\s*()(?:"(stablehlo\.custom_call)"|'
+                                r"(stablehlo\.custom_call))\b")
 _MLIR_TARGET_RE = re.compile(r"custom_call\s+@([\w.\-]+)|@([\w.\-]+)\s*\(")
 _MLIR_SCOPE_RE = re.compile(r'loc\("([^"]*)"')
 
@@ -321,10 +325,10 @@ def _normalize_mlir_op(op: str) -> str:
 def _parse_stablehlo(text: str) -> Module:
     mod = Module(dialect="stablehlo", text=text)
     for ln, line in enumerate(text.splitlines(), start=1):
-        m = _MLIR_DEF_RE.match(line)
+        m = _MLIR_DEF_RE.match(line) or _MLIR_VOID_CALL_RE.match(line)
         if not m:
             continue
-        name = m.group(1).split(":")[0].lstrip("%")
+        name = m.group(1).split(":")[0].lstrip("%") or f"void.{ln}"
         op = _normalize_mlir_op(m.group(2) or m.group(3))
         # the type annotation after ':' — `(operands) -> results` for the
         # generic form, a bare type for the pretty form

@@ -41,12 +41,14 @@ __all__ = [
     "expected_engine_stages", "SAFE_CUSTOM_CALLS", "DEFAULT_PASS_IDS",
 ]
 
-# custom-call targets that are safe to ship across processes (layout /
-# sharding markers XLA resolves internally).  Anything else — LAPACK
-# handles in particular — is a host-function pointer that does NOT
-# survive a process boundary and would segfault at call time.
+# custom-call targets that are safe to ship across processes: layout /
+# sharding markers XLA resolves internally, and Mosaic TPU kernels (the
+# compiled Pallas kernel rides inside the call's backend config and the
+# TPU runtime executes it, no host pointer involved).  Anything else —
+# LAPACK handles in particular — is a host-function pointer that does
+# NOT survive a process boundary and would segfault at call time.
 SAFE_CUSTOM_CALLS = frozenset({"Sharding", "SPMDFullToShardShape",
-                               "SPMDShardToFullShape"})
+                               "SPMDShardToFullShape", "tpu_custom_call"})
 
 # LAPACK/BLAS factorization + solve custom-call families, plus the HLO
 # ops XLA may lower them to.  Matching is substring on the custom-call

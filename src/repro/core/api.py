@@ -71,11 +71,7 @@ def _runtime_bounds(est_kw: dict) -> dict:
     rt = {}
     for name in ("lmin", "lmax"):
         v = est_kw.get(name)
-        try:
-            traced = isinstance(v, jax.core.Tracer)
-        except AttributeError:  # pragma: no cover - future jax relocations
-            traced = False
-        if traced:
+        if isinstance(v, jax.core.Tracer):
             rt[name] = est_kw.pop(name)
     return rt
 

@@ -14,11 +14,14 @@ This module replaces the constants with a **measured calibration table**:
   collective_bytes  collective payload bandwidth (bytes/s)
 
 The table is produced by ``python -m benchmarks.roofline --calibrate``
-(times a GEMM, a fused rank-1 update, and a shard_map psum loop at two
-payload sizes, then fits latency + bandwidth) and persisted as JSON.
-Search order: ``$REPRO_CALIBRATION`` (a path, or ``static`` to force the
-built-in defaults), then the committed ``bench_out/roofline_calibration
-.json``, then the static defaults.
+(times an f32 and a bf16 GEMM, a fused rank-1 update, and — when the
+process sees more than one device — a shard_map psum loop at two payload
+sizes, then fits latency + bandwidth) and persisted as JSON with one
+entry per ``device_kind`` (``jax.devices()[0].device_kind``: "cpu",
+"TPU v5 lite", ...).  Search order for the table: ``$REPRO_CALIBRATION``
+(a path, or ``static`` to force the built-in defaults — the explicit
+opt-in for tests), then the committed ``bench_out/roofline_calibration
+.json``.  A device kind with no entry is an error, never a default.
 
 The cost functions below are the single place route timings are modeled;
 `repro.core.plan.select_route` consumes them.  Because the mesh terms
@@ -34,12 +37,12 @@ import math
 import os
 from dataclasses import dataclass
 from pathlib import Path
-from typing import Optional
+from typing import Optional, Tuple
 
 __all__ = [
-    "Calibration", "STATIC_DEFAULT", "load_calibration",
-    "clear_calibration_cache", "calibration_path", "exact_cost",
-    "estimator_cost",
+    "Calibration", "CalibrationError", "STATIC_DEFAULT", "load_calibration",
+    "clear_calibration_cache", "calibration_path", "current_device_kind",
+    "exact_cost", "estimator_cost", "CALIBRATE_CMD",
 ]
 
 _ENV_VAR = "REPRO_CALIBRATION"
@@ -48,91 +51,116 @@ _TABLE_NAME = "roofline_calibration.json"
 # default) — sets how many sequential collectives an estimator run needs
 _EST_SLAB = 32
 
+CALIBRATE_CMD = "PYTHONPATH=src python -m benchmarks.roofline --calibrate"
 
-# modeled bf16:native GEMM throughput ratio when a table carries no
-# measured bf16 entry — MXU parts run bf16 matmuls at ~2x the f32 rate
-_BF16_GEMM_SPEEDUP = 2.0
+
+class CalibrationError(ValueError):
+    """No usable measured calibration for this device."""
 
 
 @dataclass(frozen=True)
 class Calibration:
     """Per-device roofline terms; see the module docstring.
 
-    ``gemm_flops_bf16`` is the optional measured bf16 GEMM rate (the
-    mixed-precision engine route); absent, `gemm_rate` models it as
-    ``_BF16_GEMM_SPEEDUP x gemm_flops`` so the selector still prices
-    bf16 and native separately.
+    ``gemm_flops_bf16`` prices the mixed-precision engine route.  The
+    collective terms are None in an entry measured on a single device;
+    pricing a mesh with such an entry raises.
     """
     gemm_flops: float = 4.0e10
     stream_bytes: float = 1.5e10
-    collective_lat: float = 2.0e-5
-    collective_bytes: float = 4.0e9
+    collective_lat: Optional[float] = 2.0e-5
+    collective_bytes: Optional[float] = 4.0e9
     source: str = "static-default"
-    gemm_flops_bf16: Optional[float] = None
+    gemm_flops_bf16: Optional[float] = 8.0e10
 
     def __post_init__(self):
         for name in ("gemm_flops", "stream_bytes", "collective_lat",
-                     "collective_bytes"):
-            v = float(getattr(self, name))
-            if not v > 0:
+                     "collective_bytes", "gemm_flops_bf16"):
+            v = getattr(self, name)
+            if v is not None and not float(v) > 0:
                 raise ValueError(f"calibration {name} must be > 0, got {v}")
-        if self.gemm_flops_bf16 is not None \
-                and not float(self.gemm_flops_bf16) > 0:
-            raise ValueError(
-                f"calibration gemm_flops_bf16 must be > 0, "
-                f"got {self.gemm_flops_bf16}")
 
     def gemm_rate(self, precision: Optional[str] = None) -> float:
         """Sustained GEMM FLOP/s for an engine precision route."""
         if precision in (None, "f32", "f64", "native"):
             return float(self.gemm_flops)
         if precision == "bf16":
-            if self.gemm_flops_bf16 is not None:
-                return float(self.gemm_flops_bf16)
-            return float(self.gemm_flops) * _BF16_GEMM_SPEEDUP
+            if self.gemm_flops_bf16 is None:
+                raise CalibrationError(
+                    f"calibration {self.source!r} has no measured bf16 GEMM "
+                    f"rate; re-measure it with `{CALIBRATE_CMD}`")
+            return float(self.gemm_flops_bf16)
         raise ValueError(f"unknown precision {precision!r}")
+
+    def collective(self) -> Tuple[float, float]:
+        """(latency s, bytes/s) of one collective; raises if unmeasured."""
+        if self.collective_lat is None or self.collective_bytes is None:
+            raise CalibrationError(
+                f"calibration {self.source!r} was measured on one device "
+                "and cannot price a mesh; run "
+                f"`{CALIBRATE_CMD}` on a host with several devices")
+        return float(self.collective_lat), float(self.collective_bytes)
 
 
 STATIC_DEFAULT = Calibration()
 
 
 def calibration_path() -> Optional[Path]:
-    """Where a measured table would be loaded from (None -> static)."""
+    """Where the table is loaded from (None -> the static defaults)."""
     env = os.environ.get(_ENV_VAR, "").strip()
     if env:
-        if env.lower() == "static":
-            return None
-        return Path(env)
-    committed = Path(__file__).resolve().parents[3] / "bench_out" / _TABLE_NAME
-    return committed if committed.exists() else None
+        return None if env.lower() == "static" else Path(env)
+    return Path(__file__).resolve().parents[3] / "bench_out" / _TABLE_NAME
+
+
+def current_device_kind() -> str:
+    """``device_kind`` of the first device: the table's key."""
+    import jax
+    return jax.devices()[0].device_kind
 
 
 @functools.lru_cache(maxsize=8)
-def _load(path_str: Optional[str]) -> Calibration:
-    if path_str is None:
-        return STATIC_DEFAULT
+def _load(path_str: str, kind: str) -> Calibration:
     try:
         raw = json.loads(Path(path_str).read_text())
     except (OSError, json.JSONDecodeError) as e:
-        raise ValueError(f"cannot read calibration table {path_str}: {e}")
-    bf16 = raw.get("bf16") or {}
-    bf16_rate = bf16.get("gemm_flops", raw.get("gemm_flops_bf16"))
+        raise CalibrationError(
+            f"cannot read calibration table {path_str}: {e}; measure one "
+            f"with `{CALIBRATE_CMD}`") from None
+    devices = raw.get("devices", {})
+    entry = devices.get(kind)
+    if entry is None:
+        raise CalibrationError(
+            f"calibration table {path_str} has no entry for device kind "
+            f"{kind!r} (it has {sorted(devices)}); measure one on this "
+            f"device with `{CALIBRATE_CMD}`")
+
+    def opt(name):
+        v = entry.get(name)
+        return None if v is None else float(v)
+
     return Calibration(
-        gemm_flops=float(raw["gemm_flops"]),
-        stream_bytes=float(raw["stream_bytes"]),
-        collective_lat=float(raw["collective_lat"]),
-        collective_bytes=float(raw["collective_bytes"]),
-        source=str(raw.get("source", f"measured:{path_str}")),
-        gemm_flops_bf16=None if bf16_rate is None else float(bf16_rate),
+        gemm_flops=float(entry["gemm_flops"]),
+        stream_bytes=float(entry["stream_bytes"]),
+        collective_lat=opt("collective_lat"),
+        collective_bytes=opt("collective_bytes"),
+        source=str(entry.get("source", f"measured:{kind}")),
+        gemm_flops_bf16=opt("gemm_flops_bf16"),
     )
 
 
-def load_calibration(path=None) -> Calibration:
-    """The active calibration table (measured if available)."""
-    if path is not None:
-        return _load(str(path))
-    p = calibration_path()
-    return _load(None if p is None else str(p))
+def load_calibration(path=None, *, device_kind: Optional[str] = None,
+                     ) -> Calibration:
+    """The measured entry for this device (or ``device_kind``).
+
+    Raises `CalibrationError` when the table has no entry for the device
+    kind; ``REPRO_CALIBRATION=static`` returns `STATIC_DEFAULT` instead.
+    """
+    if path is None:
+        path = calibration_path()
+        if path is None:
+            return STATIC_DEFAULT
+    return _load(str(path), device_kind or current_device_kind())
 
 
 def clear_calibration_cache():
@@ -160,8 +188,8 @@ def exact_cost(n: int, devices: int, cal: Calibration, *,
     ``panel_k=None`` resolves through the calibration-driven tile
     autotuner (`repro.kernels.autotune`) — the same resolution the
     kernels use, so ``method="auto"`` prices the geometry that actually
-    runs.  ``precision="bf16"`` prices the GEMM term at the measured (or
-    modeled) bf16 rate.
+    runs.  ``precision="bf16"`` prices the GEMM term at the measured bf16
+    rate.
 
     ``lookahead`` prices the pipelined mesh schedule: the double-buffered
     broadcast overlaps the bulk trailing update, hiding up to the
@@ -196,8 +224,9 @@ def exact_cost(n: int, devices: int, cal: Calibration, *,
             width = 1
         # tree/butterfly collectives pay the latency once per hop, and the
         # hop count grows with the device fan-out: ~log2(P) depth
-        lat = cal.collective_lat * max(1.0, math.log2(devices))
-        comm = steps * (lat + payload / cal.collective_bytes)
+        lat, bw = cal.collective()
+        lat = lat * max(1.0, math.log2(devices))
+        comm = steps * (lat + payload / bw)
         if lookahead:
             # the in-flight collective overlaps the bulk update: only the
             # part of comm that exceeds per-device compute stays exposed
@@ -224,5 +253,6 @@ def estimator_cost(n: int, cols: int, matvec_flops: float, devices: int,
     if devices > 1:
         seq = max(1, cols // _EST_SLAB)
         payload = itemsize * n * _EST_SLAB
-        cost += seq * (cal.collective_lat + payload / cal.collective_bytes)
+        lat, bw = cal.collective()
+        cost += seq * (lat + payload / bw)
     return cost

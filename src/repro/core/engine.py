@@ -55,16 +55,16 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec
 
-from repro._compat import (axis_size as _axis_size, pvary as _pvary,
-                           shard_map as _shard_map)
 from repro import obs
+from repro.numerics import log_abs, matmul
 
 __all__ = [
     "EngineConfig", "LEGACY_ROUTES", "SCHEDULES", "UPDATES", "BACKENDS",
     "build_serial", "build_mesh", "engine_slogdet",
     "condense_steps", "condense_full", "panel_factor", "apply_panel",
     "panel_rounds_serial", "mc_step_fn", "mc_local_phase", "mesh_tail",
-    "combine_slogdet", "guarded_pivot", "cyclic_perm", "perm_parity",
+    "combine_slogdet", "guarded_pivot", "cyclic_perm",
+    "perm_parity",
     "stage_schedule",
 ]
 
@@ -305,7 +305,7 @@ def _condense_step(buf: jax.Array, t, n_total: int, sign, logdet, *,
     # pivot (active row 0, active column m-1) => (-1)^(m-1).
     parity = jnp.where((m - 1) % 2 == 0, 1.0, -1.0).astype(buf.dtype)
     sign = sign * jnp.sign(p) * swap_sign * parity
-    logdet = logdet + jnp.log(jnp.abs(p))
+    logdet = logdet + log_abs(p)
     return buf, sign, logdet
 
 
@@ -370,13 +370,13 @@ def condense_full(a: jax.Array, *, use_kernel=False, fused: bool = False,
     if n == 0:
         return jnp.ones((), a.dtype), jnp.zeros((), a.dtype)
     if n == 1:
-        return jnp.sign(a[0, 0]), jnp.log(jnp.abs(a[0, 0]))
+        return jnp.sign(a[0, 0]), log_abs(a[0, 0])
 
     update_fn, step_fn = _step_hooks(use_kernel, fused, precision)
     buf, sign, logdet = condense_steps(a, n - 1, update_fn=update_fn,
                                        step_fn=step_fn)
     p = buf[n - 1, 0]
-    return sign * jnp.sign(p), logdet + jnp.log(jnp.abs(p))
+    return sign * jnp.sign(p), logdet + log_abs(p)
 
 
 # --------------------------------------------------------------------------
@@ -436,7 +436,7 @@ def panel_factor(panel: jax.Array, m0, *, r_pos=0, update_fn=None):
         parity = jnp.where((r_pos + m - 1) % 2 == 0, 1.0, -1.0).astype(dt)
         swap_sign = jnp.where(l == last, 1.0, -1.0).astype(dt)
         sign = sign * jnp.sign(pv) * swap_sign * parity
-        logdet = logdet + jnp.log(jnp.abs(pv))
+        logdet = logdet + log_abs(pv)
         return buf, ls, sign, logdet
 
     zero = panel[0, 0] * 0
@@ -501,15 +501,21 @@ def apply_panel(block: jax.Array, R: jax.Array, ls: jax.Array, m0,
     t_cols = lax.dynamic_slice(R, (0, m0 - K), (K, K))
     T = jnp.flip(t_cols, axis=1)
 
-    # C @ T = Pc  =>  T^T C^T = Pc^T (T^T lower, unit diagonal)
-    Ct = jax.scipy.linalg.solve_triangular(
-        T, Pc.T, trans="T", lower=False, unit_diagonal=True
-    )
-    C = Ct.T * row_mask[:, None]
+    # C @ T = Pc: substitute column by column, elementwise and in a fixed
+    # order.  On the TPU, solve_triangular gives a row last-bit different
+    # results in different programs, which broke the bit-identity of the
+    # lookahead mesh schedule with the plain one.
+    U = jnp.triu(T, 1)
+
+    def substitute(j, X):
+        xj = lax.dynamic_slice_in_dim(X, j, 1, axis=1)     # final column j
+        return X - xj * lax.dynamic_slice_in_dim(U, j, 1, axis=0)
+
+    C = lax.fori_loop(0, K, substitute, Pc) * row_mask[:, None]
 
     with obs.stage("engine.panel_apply"):
         if gemm_fn is None:
-            return block - C @ R
+            return block - matmul(C, R)
         return gemm_fn(block, C, R)
 
 
@@ -547,6 +553,8 @@ def panel_factor_dispatch(use_kernel):
         k, n = panel.shape
         if k * n * panel.dtype.itemsize <= VMEM_BUDGET:
             return _kops.panel_factor_vmem(panel, m0, r_pos, backend=req)
+        # counted, so a reader of kernel.dispatch sees the kernel skipped
+        obs.inc("kernel.dispatch", op="panel_factor_vmem", backend="xla")
         return panel_factor(panel, m0, r_pos=r_pos, update_fn=update_fn)
 
     return factor
@@ -625,7 +633,7 @@ def blocked_full(a: jax.Array, *, k: int = 32, use_kernel=False,
                                          step_fn=step_fn)
     p = buf[n - 1, 0]
     return (sign * rsign * jnp.sign(p),
-            logdet + rlogdet + jnp.log(jnp.abs(p)))
+            logdet + rlogdet + log_abs(p))
 
 
 # --------------------------------------------------------------------------
@@ -755,7 +763,7 @@ def mc_step_fn(axis_name: str, *, update_fn=None):
     def step(t, carry):
         local, sign, logdet = carry
         L, N = local.shape
-        P = _axis_size(axis_name)
+        P = lax.axis_size(axis_name)
         me = lax.axis_index(axis_name)
         i = t // P                            # round = owner's local row index
         p = t % P                             # owner device
@@ -808,7 +816,7 @@ def mc_step_fn(axis_name: str, *, update_fn=None):
         swap_sign = jnp.where(l == last, 1.0, -1.0).astype(local.dtype)
         step_sign = jnp.sign(pv) * swap_sign * parity
         sign = jnp.where(mine, sign * step_sign, sign)
-        logdet = logdet + jnp.where(mine, jnp.log(jnp.abs(pv)), 0.0)
+        logdet = logdet + jnp.where(mine, log_abs(pv), 0.0)
         return local, sign, logdet
 
     return step
@@ -822,12 +830,12 @@ def mc_local_phase(local, axis_name: str, *, t0: int = 0,
     steps starting at ``t0`` (default: the full ``(L-1)*P`` schedule).
     """
     L, N = local.shape
-    P = _axis_size(axis_name)
+    P = lax.axis_size(axis_name)
     if n_steps is None:
         n_steps = (L - 1) * P - t0
     step = mc_step_fn(axis_name, update_fn=update_fn)
-    sign0 = _pvary(jnp.ones((), local.dtype), axis_name)
-    ld0 = _pvary(jnp.zeros((), local.dtype), axis_name)
+    sign0 = lax.pcast(jnp.ones((), local.dtype), axis_name, to="varying")
+    ld0 = lax.pcast(jnp.zeros((), local.dtype), axis_name, to="varying")
     return lax.fori_loop(t0, t0 + n_steps, step, (local, sign0, ld0))
 
 
@@ -842,7 +850,7 @@ def mesh_tail(local, sign, logdet, axis_name: str):
     shard_map out_specs.
     """
     L, N = local.shape
-    P = _axis_size(axis_name)
+    P = lax.axis_size(axis_name)
     with obs.stage("engine.mesh_tail"):
         # slice the live columns (the [0, P) prefix) BEFORE the gather:
         # the collective moves 8*P^2 bytes, not 8*N*P — gathering full
@@ -883,7 +891,7 @@ def _mesh_panel_kernel(axis_name: str, k: int, *, gemm_fn=None,
 
     def kernel(local):
         L, N = local.shape
-        P = _axis_size(axis_name)
+        P = lax.axis_size(axis_name)
         me = lax.axis_index(axis_name)
         n_rounds = (L - 1) // k
         lrow = jnp.arange(L)
@@ -987,7 +995,7 @@ def _mesh_rank1_lookahead_kernel(axis_name: str, update_fn=None):
 
     def kernel(local):
         L, N = local.shape
-        P = _axis_size(axis_name)
+        P = lax.axis_size(axis_name)
         me = lax.axis_index(axis_name)
         dt = local.dtype
         zero = local[0, 0] * 0                # device-varying scalar zero
@@ -1008,7 +1016,7 @@ def _mesh_rank1_lookahead_kernel(axis_name: str, update_fn=None):
             swap_sign = jnp.where(l == m - 1, 1.0, -1.0).astype(dt)
             step_sign = jnp.sign(pv) * swap_sign * parity
             sign = jnp.where(mine, sign * step_sign, sign)
-            logdet = logdet + jnp.where(mine, jnp.log(jnp.abs(pv)), zero)
+            logdet = logdet + jnp.where(mine, log_abs(pv), zero)
             return sign, logdet
 
         # prologue: step 0's pivot row, broadcast in flight before the loop
@@ -1107,7 +1115,7 @@ def _mesh_panel_lookahead_kernel(axis_name: str, k: int, *, gemm_fn=None,
 
     def kernel(local):
         L, N = local.shape
-        P = _axis_size(axis_name)
+        P = lax.axis_size(axis_name)
         me = lax.axis_index(axis_name)
         n_rounds = (L - 1) // k
         n_panels = n_rounds * P
@@ -1265,7 +1273,7 @@ def build_mesh(cfg: EngineConfig, mesh, axis_name: str = "rows", *,
                                     gemm_fn=gemm_fn, update_fn=update_fn,
                                     factor_fn=factor_fn)
 
-    shmapped = _shard_map(
+    shmapped = jax.shard_map(
         kernel,
         mesh=mesh,
         in_specs=(PartitionSpec(axis_name, None),),

@@ -23,11 +23,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro._compat import (axis_size as _axis_size, pvary as _pvary,
-                           shard_map as _shard_map)
 # GE stays a separate algorithm family (the paper's comparison baseline),
 # but the sign/permutation helpers are the engine's shared ones
 from repro.core.engine import cyclic_perm, guarded_pivot, perm_parity
+from repro.numerics import log_abs
 
 __all__ = ["slogdet_ge", "parallel_slogdet_ge", "ge_step_fn", "cyclic_perm", "perm_parity"]
 
@@ -68,7 +67,7 @@ def slogdet_ge(a: jax.Array):
         buf = buf - factor[:, None] * pr[None, :]
 
         sign = sign * jnp.sign(p)
-        logdet = logdet + jnp.log(jnp.abs(p))
+        logdet = logdet + log_abs(p)
         return buf, sign, logdet
 
     buf, sign, logdet = lax.fori_loop(
@@ -88,7 +87,7 @@ def ge_step_fn(axis_name: str):
     def step(t, carry):
         local, sign, logdet = carry
         L, N = local.shape
-        P = _axis_size(axis_name)
+        P = lax.axis_size(axis_name)
         me = lax.axis_index(axis_name)
         lrow = jnp.arange(L)
         grow = lrow * P + me                     # global index of each local row
@@ -131,7 +130,7 @@ def ge_step_fn(axis_name: str):
 
         sign = sign * jnp.where(swapped, -1.0, 1.0).astype(local.dtype)
         sign = sign * jnp.sign(p)
-        logdet = logdet + jnp.log(jnp.abs(p))
+        logdet = logdet + log_abs(p)
         return local, sign, logdet
 
     return step
@@ -153,13 +152,13 @@ def parallel_slogdet_ge(mesh, axis_name: str = "rows"):
     def kernel(local):
         # local: (L, N) cyclic block, row-major as in the paper
         N = local.shape[1]
-        sign0 = _pvary(jnp.ones((), local.dtype), axis_name)
-        ld0 = _pvary(jnp.zeros((), local.dtype), axis_name)
+        sign0 = lax.pcast(jnp.ones((), local.dtype), axis_name, to="varying")
+        ld0 = lax.pcast(jnp.zeros((), local.dtype), axis_name, to="varying")
         local, sign, logdet = lax.fori_loop(0, N, step, (local, sign0, ld0))
         # sign/logdet are accumulated identically on all devices.
         return sign.reshape(1), logdet.reshape(1)
 
-    shmapped = _shard_map(
+    shmapped = jax.shard_map(
         kernel,
         mesh=mesh,
         in_specs=(PartitionSpec(axis_name, None),),

@@ -62,6 +62,7 @@ from typing import Any, Callable, Optional, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro import obs
 
@@ -90,10 +91,7 @@ _BOUNDS_COLS = 2 * (32 + 1)
 
 
 def _is_tracer(x) -> bool:
-    try:
-        return isinstance(x, jax.core.Tracer)
-    except AttributeError:  # pragma: no cover - future jax relocations
-        return False
+    return isinstance(x, jax.core.Tracer)
 
 
 def _mark_trace(trace_log: list) -> None:
@@ -371,6 +369,28 @@ def _parallel_kernel(method: str, cfg: ExactConfig, mesh, axis_name: str):
     return fn
 
 
+def _shard_rows(a, dtype, mesh, axis_name: str, mult: int):
+    """``a`` as ``dtype``, embedded in ``diag(a, I)`` to a multiple of
+    ``mult`` rows, row-sharded over ``mesh``.
+
+    A host array goes straight to its shards, so no device ever holds
+    the whole matrix; a device array is resharded where it lives.
+    """
+    if _is_tracer(a):
+        return jnp.asarray(a, dtype)
+    from repro.core.api import pad_to_multiple
+    rows = NamedSharding(mesh, PartitionSpec(axis_name, None))
+    if isinstance(a, jax.Array):
+        return jax.device_put(pad_to_multiple(a.astype(dtype), mult), rows)
+    a = np.asarray(a, dtype)
+    n = a.shape[0]
+    pad = (-n) % mult
+    if pad:
+        a = np.pad(a, ((0, pad), (0, pad)))
+        a[np.arange(n, n + pad), np.arange(n, n + pad)] = 1
+    return jax.device_put(a, rows)
+
+
 def _widen_bounds_for_padding(kw: dict) -> dict:
     """diag(A, I) padding adds unit eigenvalues: user-supplied Chebyshev
     bounds must be widened to bracket 1, else T_j blows up outside [-1, 1]
@@ -407,8 +427,7 @@ def _build_forward(spec: ProblemSpec, method: str, cfg: LogdetConfig,
 
             def fwd(a, key=None, probes=None):  # eager: pfn is jitted inside
                 trace_log.append(1)
-                a = jnp.asarray(a, dtype)
-                s, ld = wrapped(a)
+                s, ld = wrapped(_shard_rows(a, dtype, mesh, axis_name, mult))
                 return s, ld, jnp.zeros(ld.shape, ld.dtype)
 
             return fwd, False, padded_n
@@ -1024,6 +1043,7 @@ def plan(x, *, method: str = "auto", mesh=None, axis_name: str = "rows",
         cfg = dataclasses.replace(cfg, precision=engine_precision)
     if method == "exact":
         cfg = cfg.resolved(mesh_present=mesh is not None)
+    _refuse_f64_kernels(method, cfg, spec)
 
     if spec.kind == "operator":
         if method not in ESTIMATOR_METHODS:
@@ -1096,6 +1116,29 @@ def plan(x, *, method: str = "auto", mesh=None, axis_name: str = "rows",
         while len(_PLAN_CACHE) > _PLAN_CACHE_SIZE:
             _PLAN_CACHE.popitem(last=False)
     return _bind(p, x)
+
+
+def _refuse_f64_kernels(method: str, cfg: LogdetConfig,
+                        spec: ProblemSpec) -> None:
+    """Refuse, at plan time, a float64 plan whose Pallas kernels would be
+    compiled for the TPU: Mosaic has no float64, so it would otherwise
+    fail at the first call as a kernel compile error."""
+    from repro.kernels import ops as _kops
+    if spec.dtype != "float64" or not _kops.on_tpu():
+        return
+    if method == "exact":
+        backend = cfg.backend
+    elif method in ESTIMATOR_METHODS:
+        backend = _kops.kernel_backend()
+    else:
+        return
+    if backend == "pallas":
+        raise ValueError(
+            f"a float64 {method!r} plan cannot run the Pallas kernels on the "
+            "TPU (Mosaic has no float64): plan in float32 "
+            "(precision='float32' or a float32 input), or use the xla "
+            "backend (REPRO_KERNEL_BACKEND=xla, or backend='xla' for "
+            "method='exact')")
 
 
 def _bind(p: LogdetPlan, x) -> LogdetPlan:

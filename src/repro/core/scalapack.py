@@ -29,9 +29,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec
 
-from repro._compat import (axis_size as _axis_size, pvary as _pvary,
-                           shard_map as _shard_map)
 from repro.core.engine import cyclic_perm, guarded_pivot, perm_parity
+from repro.numerics import log_abs
 
 __all__ = ["parallel_slogdet_lu"]
 
@@ -42,7 +41,7 @@ def parallel_slogdet_lu(mesh, axis_name: str = "rows", *, nb: int = 1):
 
     def kernel(local):
         L, N = local.shape
-        P = _axis_size(axis_name)
+        P = lax.axis_size(axis_name)
         me = lax.axis_index(axis_name)
         lrow = jnp.arange(L)
         grow = lrow * P + me
@@ -89,7 +88,7 @@ def parallel_slogdet_lu(mesh, axis_name: str = "rows", *, nb: int = 1):
 
             sign = sign * jnp.where(swapped, -1.0, 1.0).astype(local.dtype)
             sign = sign * jnp.sign(p)
-            logdet = logdet + jnp.log(jnp.abs(p))
+            logdet = logdet + log_abs(p)
             return local, F, sign, logdet, t0
 
         def panel_step(q, carry):
@@ -111,12 +110,12 @@ def parallel_slogdet_lu(mesh, axis_name: str = "rows", *, nb: int = 1):
             local = local - F_gemm @ U12
             return local, sign, logdet
 
-        carry = (local, _pvary(jnp.ones((), local.dtype), axis_name),
-                 _pvary(jnp.zeros((), local.dtype), axis_name))
+        one = lax.pcast(jnp.ones((), local.dtype), axis_name, to="varying")
+        carry = (local, one, jnp.zeros_like(one))
         local, sign, logdet = lax.fori_loop(0, n_panels, panel_step, carry)
         return sign.reshape(1), logdet.reshape(1)
 
-    shmapped = _shard_map(
+    shmapped = jax.shard_map(
         kernel,
         mesh=mesh,
         in_specs=(PartitionSpec(axis_name, None),),

@@ -63,6 +63,7 @@ from repro.estimators.operators import (
 )
 from repro.estimators.slq import logdet_slq
 from repro.kernels.ref import stencil_mv_ref
+from repro.numerics import full_precision
 
 __all__ = [
     "estimate_logdet", "exact_slogdet_vjp", "hutchinson_pullback",
@@ -180,7 +181,9 @@ def exact_slogdet_vjp(fn: Callable[[jax.Array], Any]):
         g = ct[1]                                  # logdet cotangent only
         if a.shape[-1] == 0:
             return (jnp.zeros_like(a),)
-        bar = g * jnp.swapaxes(jnp.linalg.inv(a), -1, -2)
+        with full_precision():
+            inv = jnp.linalg.inv(a)
+        bar = g * jnp.swapaxes(inv, -1, -2)
         return (bar.astype(a.dtype),)
 
     f.defvjp(f_fwd, f_bwd)

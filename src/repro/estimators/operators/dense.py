@@ -13,6 +13,7 @@ import jax.numpy as jnp
 from repro.estimators.operators.base import (
     LinearOperator, PlanHints, check_square,
 )
+from repro.numerics import matmul
 
 __all__ = ["DenseOperator"]
 
@@ -28,16 +29,16 @@ class DenseOperator(LinearOperator):
         self.dtype = a.dtype
 
     def mm(self, v):
-        return self.a @ v
+        return matmul(self.a, v)
 
     def mv(self, v):
-        return self.a @ v
+        return matmul(self.a, v)
 
     def rmm(self, v):
-        return self.a.T @ v
+        return matmul(self.a.T, v)
 
     def rmv(self, v):
-        return self.a.T @ v
+        return matmul(self.a.T, v)
 
     def diag(self):
         return jnp.diagonal(self.a)

@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
-from repro._compat import shard_map as _shard_map
 from repro.estimators.operators.base import (
     LinearOperator, PlanHints, check_square,
 )
@@ -44,7 +43,7 @@ def _sharded_mm(mesh, axis_name: str, use_kernel: bool):
         return local @ v
 
     in_specs, out_specs = rowwise_matvec_specs(axis_name)
-    return jax.jit(_shard_map(kernel, mesh=mesh,
+    return jax.jit(jax.shard_map(kernel, mesh=mesh,
                               in_specs=in_specs, out_specs=out_specs))
 
 

@@ -104,14 +104,6 @@ def _parse_override(env: str):
         **fields})
 
 
-def _gemm_rate(cal, precision):
-    """Per-dtype sustained GEMM rate from the calibration table."""
-    rate_fn = getattr(cal, "gemm_rate", None)
-    if rate_fn is not None:
-        return float(rate_fn(precision))
-    return float(cal.gemm_flops)
-
-
 def _model_cost(k: int, n: int, itemsize: int, gemm: float,
                 stream: float) -> float:
     """Modeled seconds for one n x n condensation at panel width k.
@@ -130,12 +122,8 @@ def _model_cost(k: int, n: int, itemsize: int, gemm: float,
     return gemm_t + sweep_t + factor_t
 
 
-@functools.lru_cache(maxsize=64)
-def _tuned(fingerprint: str, n_bucket: int, itemsize: int,
-           precision, cal_key: str) -> TileConfig:
-    from repro.core.calibration import load_calibration
-    cal = load_calibration()
-    gemm = _gemm_rate(cal, precision)
+def _model(cal, n_bucket: int, itemsize: int, precision) -> TileConfig:
+    gemm = cal.gemm_rate(precision)
     stream = float(cal.stream_bytes)
     cap = max(PANEL_K_CANDIDATES[0], n_bucket // 4)
     cands = [k for k in PANEL_K_CANDIDATES if k <= cap] \
@@ -145,6 +133,13 @@ def _tuned(fingerprint: str, n_bucket: int, itemsize: int,
     bm, bn = _BLOCKS_BY_ITEMSIZE.get(itemsize, _DEFAULT_BLOCKS)
     return TileConfig(panel_k=best, block_m=bm, block_n=bn,
                       source=f"model:{cal.source}")
+
+
+@functools.lru_cache(maxsize=64)
+def _tuned(fingerprint: str, n_bucket: int, itemsize: int,
+           precision, cal_key: str) -> TileConfig:
+    from repro.core.calibration import load_calibration
+    return _model(load_calibration(), n_bucket, itemsize, precision)
 
 
 def tile_config(n: int, *, itemsize: int = 4, precision=None,
@@ -161,16 +156,7 @@ def tile_config(n: int, *, itemsize: int = 4, precision=None,
         return override
     n_bucket = 1 << max(3, int(math.ceil(math.log2(max(2, int(n))))))
     if cal is not None:
-        gemm = _gemm_rate(cal, precision)
-        stream = float(cal.stream_bytes)
-        cap = max(PANEL_K_CANDIDATES[0], n_bucket // 4)
-        cands = [k for k in PANEL_K_CANDIDATES if k <= cap] \
-            or [PANEL_K_CANDIDATES[0]]
-        best = min(cands, key=lambda k: _model_cost(k, n_bucket, itemsize,
-                                                    gemm, stream))
-        bm, bn = _BLOCKS_BY_ITEMSIZE.get(itemsize, _DEFAULT_BLOCKS)
-        return TileConfig(panel_k=best, block_m=bm, block_n=bn,
-                          source=f"model:{cal.source}")
+        return _model(cal, n_bucket, int(itemsize), precision)
     from repro.core.calibration import load_calibration
     cal_key = load_calibration().source
     return _tuned(device_fingerprint(), n_bucket, int(itemsize),

@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import I0, out_struct, vary_like
+
 __all__ = ["rank1_update_kernel", "rank1_update_pallas"]
 
 DEFAULT_BM = 256
@@ -47,6 +49,7 @@ def rank1_update_pallas(a: jax.Array, pc: jax.Array, pr: jax.Array, *,
                         interpret: bool = False) -> jax.Array:
     """a (M, N) - outer(pc (M,), pr (N,)) via a tiled Pallas kernel."""
     m, n = a.shape
+    pc, pr = vary_like(a, pc, pr)
     bm = min(bm, m)
     bn = min(bn, n)
     grid = (pl.cdiv(m, bm), pl.cdiv(n, bn))
@@ -55,10 +58,10 @@ def rank1_update_pallas(a: jax.Array, pc: jax.Array, pr: jax.Array, *,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
+            pl.BlockSpec((bm, 1), lambda i, j: (i, I0)),
+            pl.BlockSpec((1, bn), lambda i, j: (I0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
+        out_shape=out_struct((m, n), a.dtype, a),
         interpret=interpret,
     )(a, pc[:, None], pr[None, :])

@@ -25,6 +25,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import I0
+from repro.numerics import dot_precision
+
 __all__ = [
     "cheb_step_kernel", "cheb_step_pallas",
     "cg_step_kernel", "cg_step_pallas", "VMEM_BUDGET",
@@ -44,7 +47,8 @@ def cheb_step_kernel(a_ref, w_ref, wprev_ref, v_ref, center_ref,
     w = w_ref[...]
     center = center_ref[0]
     width = width_ref[0]
-    aw = jnp.dot(a, w, preferred_element_type=_pref(a.dtype)).astype(a.dtype)
+    aw = jnp.dot(a, w, precision=dot_precision(a.dtype),
+                 preferred_element_type=_pref(a.dtype)).astype(a.dtype)
     mv = (2.0 * aw - center * w) / width
     w_next = 2.0 * mv - wprev_ref[...]
     wnext_ref[...] = w_next
@@ -62,16 +66,16 @@ def cheb_step_pallas(a: jax.Array, w: jax.Array, w_prev: jax.Array,
     w_next, dots = pl.pallas_call(
         cheb_step_kernel,
         in_specs=[
-            pl.BlockSpec((n, n), lambda: (0, 0)),   # A, VMEM-resident
-            pl.BlockSpec((n, k), lambda: (0, 0)),   # w
-            pl.BlockSpec((n, k), lambda: (0, 0)),   # w_prev
-            pl.BlockSpec((n, k), lambda: (0, 0)),   # v
-            pl.BlockSpec((1,), lambda: (0,)),       # center
-            pl.BlockSpec((1,), lambda: (0,)),       # width
+            pl.BlockSpec((n, n), lambda: (I0, I0)),   # A, VMEM-resident
+            pl.BlockSpec((n, k), lambda: (I0, I0)),   # w
+            pl.BlockSpec((n, k), lambda: (I0, I0)),   # w_prev
+            pl.BlockSpec((n, k), lambda: (I0, I0)),   # v
+            pl.BlockSpec((1,), lambda: (I0,)),       # center
+            pl.BlockSpec((1,), lambda: (I0,)),       # width
         ],
         out_specs=[
-            pl.BlockSpec((n, k), lambda: (0, 0)),
-            pl.BlockSpec((k,), lambda: (0,)),
+            pl.BlockSpec((n, k), lambda: (I0, I0)),
+            pl.BlockSpec((k,), lambda: (I0,)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n, k), a.dtype),
@@ -86,7 +90,8 @@ def cg_step_kernel(a_ref, p_ref, x_ref, r_ref, rz_ref, x_out, r_out):
     """ap = A p; alpha = rz / p.ap (0/0 -> 0); x += alpha p; r -= alpha ap."""
     a = a_ref[...]
     p = p_ref[...]
-    ap = jnp.dot(a, p, preferred_element_type=_pref(a.dtype)).astype(a.dtype)
+    ap = jnp.dot(a, p, precision=dot_precision(a.dtype),
+                 preferred_element_type=_pref(a.dtype)).astype(a.dtype)
     den = (p * ap).sum(0)
     rz = rz_ref[...]
     # same guarded division as operators.solve._safe_div: converged
@@ -107,15 +112,15 @@ def cg_step_pallas(a: jax.Array, p: jax.Array, x: jax.Array, r: jax.Array,
     x_new, r_new = pl.pallas_call(
         cg_step_kernel,
         in_specs=[
-            pl.BlockSpec((n, n), lambda: (0, 0)),   # A, VMEM-resident
-            pl.BlockSpec((n, k), lambda: (0, 0)),   # p
-            pl.BlockSpec((n, k), lambda: (0, 0)),   # x
-            pl.BlockSpec((n, k), lambda: (0, 0)),   # r
-            pl.BlockSpec((k,), lambda: (0,)),       # rz
+            pl.BlockSpec((n, n), lambda: (I0, I0)),   # A, VMEM-resident
+            pl.BlockSpec((n, k), lambda: (I0, I0)),   # p
+            pl.BlockSpec((n, k), lambda: (I0, I0)),   # x
+            pl.BlockSpec((n, k), lambda: (I0, I0)),   # r
+            pl.BlockSpec((k,), lambda: (I0,)),       # rz
         ],
         out_specs=[
-            pl.BlockSpec((n, k), lambda: (0, 0)),
-            pl.BlockSpec((n, k), lambda: (0, 0)),
+            pl.BlockSpec((n, k), lambda: (I0, I0)),
+            pl.BlockSpec((n, k), lambda: (I0, I0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n, k), a.dtype),

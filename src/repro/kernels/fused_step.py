@@ -34,6 +34,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from repro.kernels import I0
+
 __all__ = ["fused_step_kernel", "fused_step_pallas"]
 
 
@@ -73,13 +75,13 @@ def fused_step_pallas(a: jax.Array, l, last, pc: jax.Array, pr: jax.Array,
         functools.partial(fused_step_kernel, bn=bn),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda i, j: (0,)),       # l
-            pl.BlockSpec((1,), lambda i, j: (0,)),       # last
+            pl.BlockSpec((1,), lambda i, j: (I0,)),       # l
+            pl.BlockSpec((1,), lambda i, j: (I0,)),       # last
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),  # a tile
-            pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),   # col_l slab
-            pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),   # col_last slab
-            pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),   # pc slab
-            pl.BlockSpec((1, bn), lambda i, j: (0, j)),   # pr slab
+            pl.BlockSpec((bm, 1), lambda i, j: (i, I0)),   # col_l slab
+            pl.BlockSpec((bm, 1), lambda i, j: (i, I0)),   # col_last slab
+            pl.BlockSpec((bm, 1), lambda i, j: (i, I0)),   # pc slab
+            pl.BlockSpec((1, bn), lambda i, j: (I0, j)),   # pr slab
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),

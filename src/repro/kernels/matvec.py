@@ -25,6 +25,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import I0, out_struct, vary_like
+from repro.numerics import dot_precision
+
 __all__ = ["matvec_kernel", "matvec_pallas"]
 
 DEFAULT_BM = 256
@@ -38,6 +41,7 @@ def matvec_kernel(a_ref, x_ref, o_ref):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     o_ref[...] += jnp.dot(a_ref[...], x_ref[...],
+                          precision=dot_precision(a_ref.dtype),
                           preferred_element_type=o_ref.dtype)
 
 
@@ -47,6 +51,7 @@ def matvec_pallas(a: jax.Array, x: jax.Array, *,
                   interpret: bool = False) -> jax.Array:
     """``a (M, N) @ x (N,) or (N, K)`` via a tiled Pallas kernel."""
     vec = x.ndim == 1
+    (x,) = vary_like(a, x)
     x2 = x[:, None] if vec else x
     m, n = a.shape
     k = x2.shape[1]
@@ -66,10 +71,10 @@ def matvec_pallas(a: jax.Array, x: jax.Array, *,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((bn, k), lambda i, j: (j, 0)),
+            pl.BlockSpec((bn, k), lambda i, j: (j, I0)),
         ],
-        out_specs=pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, k), a.dtype),
+        out_specs=pl.BlockSpec((bm, k), lambda i, j: (i, I0)),
+        out_shape=out_struct((m, k), a.dtype, a),
         interpret=interpret,
     )(a, x2.astype(a.dtype))
     return out[:, 0] if vec else out

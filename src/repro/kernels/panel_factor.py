@@ -3,16 +3,27 @@
 The blocked algorithm's panel factorization runs k rank-1 condensation steps
 over a (k, N) panel.  Done with jnp ops, each step re-reads and re-writes the
 panel through HBM: 8*k^2*N bytes per panel — at k ~ L this costs as much
-traffic as the whole baseline.  But a (k, N) f32 panel at k=16..32, N<=64k is
-2..8 MiB — it FITS IN VMEM.  This kernel keeps the panel resident for all k
-steps: HBM traffic drops to one read + one write (8*k*N), a k-fold cut —
-the TPU-native realization of the paper's §2.4 cache-contiguity insight.
+traffic as the whole baseline.  But a (k, N) f32 panel at k=32, N<=32k is
+at most 4 MiB — it FITS IN VMEM.  This kernel keeps the panel resident for
+all k steps: HBM traffic drops to one read + one write (8*k*N), a k-fold
+cut — the TPU-native realization of the paper's §2.4 cache-contiguity
+insight.
 
-Single-block kernel (grid=()): panel must satisfy k*N*4B <= ~8 MiB.
-Scalars (live column count m0, sign parity offset r_pos) ride in as (1,)
-int32 inputs.  Outputs: factorized panel R (k,N) in final swapped
-coordinates, chosen pivot columns ls (k,), and the panel's (sign, logdet)
-contribution — bit-identical semantics to core.blocked.panel_factor.
+Single-block kernel (grid=()): panel must satisfy k*N*itemsize <= 4 MiB.
+The input and output copies plus the select temporaries of a 4 MiB panel
+fit v5e's default 16 MiB scoped VMEM; an 8 MiB panel does not.
+Scalars (live column count m0, sign parity offset r_pos) ride in through
+SMEM.  Outputs: factorized panel R (k,N) in final swapped coordinates,
+chosen pivot columns ls (k,), and the panel's (sign, logdet) contribution —
+bit-identical semantics to core.engine.panel_factor.  The kernel returns
+the k pivots and the wrapper sums their logs, because Mosaic cannot lower
+the accurate log (`repro.numerics.log_abs`).
+
+Mosaic has no lowering for traced-index gathers and scatters, so every
+row/column access is a masked select against an iota, and every read of
+one element or column is a one-hot reduction (exact: the other addends
+are zeros).  The argmax is max-then-first-index, which is what
+``jnp.argmax`` returns.
 """
 from __future__ import annotations
 
@@ -22,61 +33,81 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import out_struct, vary_like
+from repro.numerics import log_abs
 
 __all__ = ["panel_factor_kernel", "panel_factor_pallas"]
 
-VMEM_BUDGET = 8 * 1024 * 1024  # bytes; panel must fit
+VMEM_BUDGET = 4 * 1024 * 1024  # bytes; panel must fit
+
+
+def _pick(mask, x, axis):
+    """The one element of ``x`` under ``mask`` along ``axis`` (keepdims)."""
+    return jnp.sum(jnp.where(mask, x, jnp.zeros_like(x)), axis=axis,
+                   keepdims=True)
 
 
 def panel_factor_kernel(m0_ref, rpos_ref, panel_ref, r_ref, ls_ref,
-                        sign_ref, logdet_ref):
+                        pivots_ref, sign_ref):
     k, n = panel_ref.shape
+    dt = panel_ref.dtype
+    one = jnp.ones((1, 1), dt)
     m0 = m0_ref[0]
     r_pos = rpos_ref[0]
-    cols = lax.broadcasted_iota(jnp.int32, (n,), 0)
+    cols = lax.broadcasted_iota(jnp.int32, (1, n), 1)
+    rows = lax.broadcasted_iota(jnp.int32, (k, 1), 0)
+    steps = lax.broadcasted_iota(jnp.int32, (1, k), 1)
 
-    def body(j, carry):
-        buf, ls, sign, logdet = carry
+    def body(carry):
+        j, ls, pivots, sign = carry
+        buf = r_ref[...]
         m = m0 - j
         last = m - 1
-        row = buf[j]
+        row = _pick(rows == j, buf, 0)                       # (1, n)
         absrow = jnp.where(cols < m, jnp.abs(row), -jnp.inf)
-        l = jnp.argmax(absrow).astype(jnp.int32)
-        pv = row[l]
+        top = jnp.max(absrow, axis=1, keepdims=True)
+        l = jnp.min(jnp.where(absrow == top, cols, jnp.int32(n)), axis=1,
+                    keepdims=True)                           # (1, 1)
+        pv = _pick(cols == l, row, 1)
 
-        cl = jnp.take(buf, l, axis=1)
-        clast = jnp.take(buf, last, axis=1)
-        buf = buf.at[:, l].set(clast)
-        buf = buf.at[:, last].set(cl)
+        # swap columns l <-> last across the whole panel
+        cl = _pick(cols == l, buf, 1)                        # (k, 1)
+        clast = _pick(cols == last, buf, 1)
+        buf = jnp.where(cols == l, clast, jnp.where(cols == last, cl, buf))
 
-        row = buf[j]
-        safe = jnp.where(pv == 0, jnp.ones((), buf.dtype), pv)
+        # normalize the pivot row; store it back (it becomes R[j])
+        row = _pick(rows == j, buf, 0)
+        safe = jnp.where(pv == 0, one, pv)
         pr = jnp.where(pv == 0, jnp.zeros_like(row), row / safe)
-        pr = pr.at[last].set(jnp.where(pv == 0, pr[last], 1.0))
-        buf = buf.at[j].set(pr)
+        pr = jnp.where((cols == last) & (pv != 0), one, pr)
+        buf = jnp.where(rows == j, pr, buf)
 
-        pc = jnp.take(buf, last, axis=1)
-        pc = jnp.where(lax.broadcasted_iota(jnp.int32, (k,), 0) <= j, 0.0, pc)
-        buf = buf - pc[:, None] * pr[None, :]
-        # the pivot row was overwritten by the update of itself with pc=0;
-        # (pc[j]==0 so row j is untouched — already pr)
+        # rank-1 update of the remaining panel rows (j+1..k-1)
+        pc = _pick(cols == last, buf, 1)
+        pc = jnp.where(rows <= j, jnp.zeros_like(pc), pc)
+        r_ref[...] = buf - pc * pr
 
-        ls = ls.at[j].set(l)
-        parity = jnp.where((r_pos + m - 1) % 2 == 0, 1.0, -1.0).astype(buf.dtype)
-        swap_sign = jnp.where(l == last, 1.0, -1.0).astype(buf.dtype)
-        sign = sign * jnp.sign(pv) * swap_sign * parity
-        logdet = logdet + jnp.log(jnp.abs(pv))
-        return buf, ls, sign, logdet
+        ls = jnp.where(steps == j, l, ls)
+        pivots = jnp.where(steps == j, pv, pivots)
+        odd = lax.rem(r_pos + m - 1, jnp.int32(2)) != 0
+        parity = jnp.where(odd, -one, one)
+        swap_sign = jnp.where(l == last, one, -one)
+        pv_sign = jnp.where(pv > 0, one, jnp.where(pv < 0, -one, 0 * one))
+        sign = sign * pv_sign * swap_sign * parity
+        return j + 1, ls, pivots, sign
 
-    buf0 = panel_ref[...]
-    ls0 = jnp.zeros((k,), jnp.int32)
-    one = jnp.ones((), buf0.dtype)
-    zero = jnp.zeros((), buf0.dtype)
-    buf, ls, sign, logdet = lax.fori_loop(0, k, body, (buf0, ls0, one, zero))
-    r_ref[...] = buf
+    r_ref[...] = panel_ref[...]
+    # a while_loop, not fori_loop: fori_loop's static bounds give an i64
+    # index under jax_enable_x64, and Mosaic has no 64-bit integers
+    _, ls, pivots, sign = lax.while_loop(
+        lambda c: c[0] < k, body,
+        (jnp.int32(0), jnp.zeros((1, k), jnp.int32), jnp.zeros((1, k), dt),
+         one))
     ls_ref[...] = ls
-    sign_ref[0] = sign
-    logdet_ref[0] = logdet
+    pivots_ref[...] = pivots
+    sign_ref[...] = sign
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -86,27 +117,24 @@ def panel_factor_pallas(panel: jax.Array, m0, r_pos=0, *,
     k, n = panel.shape
     if k * n * panel.dtype.itemsize > VMEM_BUDGET:
         raise ValueError(f"panel {panel.shape} exceeds VMEM budget")
-    m0 = jnp.asarray(m0, jnp.int32).reshape(1)
-    r_pos = jnp.asarray(r_pos, jnp.int32).reshape(1)
-    r, ls, sign, logdet = pl.pallas_call(
+    m0, r_pos = vary_like(panel, jnp.asarray(m0, jnp.int32).reshape(1),
+                          jnp.asarray(r_pos, jnp.int32).reshape(1))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    r, ls, pivots, sign = pl.pallas_call(
         panel_factor_kernel,
-        in_specs=[
-            pl.BlockSpec((1,), lambda: (0,)),      # m0   (SMEM-able scalar)
-            pl.BlockSpec((1,), lambda: (0,)),      # r_pos
-            pl.BlockSpec((k, n), lambda: (0, 0)),  # the VMEM-resident panel
-        ],
-        out_specs=[
-            pl.BlockSpec((k, n), lambda: (0, 0)),
-            pl.BlockSpec((k,), lambda: (0,)),
-            pl.BlockSpec((1,), lambda: (0,)),
-            pl.BlockSpec((1,), lambda: (0,)),
-        ],
+        in_specs=[smem, smem, vmem],
+        out_specs=[vmem, vmem, vmem, vmem],
         out_shape=[
-            jax.ShapeDtypeStruct((k, n), panel.dtype),
-            jax.ShapeDtypeStruct((k,), jnp.int32),
-            jax.ShapeDtypeStruct((1,), panel.dtype),
-            jax.ShapeDtypeStruct((1,), panel.dtype),
+            out_struct((k, n), panel.dtype, panel),
+            out_struct((1, k), jnp.int32, panel),
+            out_struct((1, k), panel.dtype, panel),
+            out_struct((1, 1), panel.dtype, panel),
         ],
         interpret=interpret,
     )(m0, r_pos, panel)
-    return r, ls, sign[0], logdet[0]
+    # the pivots' logs, summed in the engine's order (Mosaic has no
+    # accurate log)
+    logdet = lax.fori_loop(0, k, lambda j, acc: acc + log_abs(pivots[0, j]),
+                           pivots[0, 0] * 0)
+    return r, ls[0], sign[0, 0], logdet

@@ -26,6 +26,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import I0, out_struct, vary_like
+from repro.numerics import dot_precision
+
 __all__ = ["panel_update_kernel", "panel_update_pallas"]
 
 DEFAULT_BM = 256
@@ -39,6 +42,7 @@ def panel_update_kernel(a_ref, c_ref, r_ref, o_ref):
     r = r_ref[...]              # (K, bn)
     acc = jax.lax.dot_general(
         c, r, (((1,), (0,)), ((), ())),
+        precision=dot_precision(c.dtype),
         preferred_element_type=jnp.float32 if a.dtype != jnp.float64 else jnp.float64,
     )
     o_ref[...] = a - acc.astype(a.dtype)
@@ -53,6 +57,7 @@ def panel_update_pallas(a: jax.Array, c: jax.Array, r: jax.Array, *,
     k = c.shape[1]
     if r.shape != (k, n) or c.shape != (m, k):
         raise ValueError(f"shape mismatch: a={a.shape} c={c.shape} r={r.shape}")
+    c, r = vary_like(a, c, r)
     bm = min(bm, m)
     bn = min(bn, n)
     grid = (pl.cdiv(m, bm), pl.cdiv(n, bn))
@@ -61,10 +66,10 @@ def panel_update_pallas(a: jax.Array, c: jax.Array, r: jax.Array, *,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((k, bn), lambda i, j: (0, j)),
+            pl.BlockSpec((bm, k), lambda i, j: (i, I0)),
+            pl.BlockSpec((k, bn), lambda i, j: (I0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
+        out_shape=out_struct((m, n), a.dtype, a),
         interpret=interpret,
     )(a, c, r)

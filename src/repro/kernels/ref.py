@@ -1,8 +1,13 @@
-"""Pure-jnp oracles for every Pallas kernel (numerical ground truth)."""
+"""Pure-jnp oracles for every Pallas kernel (numerical ground truth).
+
+Every contraction takes full float32 products (`repro.numerics`).
+"""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from repro.numerics import matmul
 
 __all__ = ["rank1_update_ref", "panel_update_ref", "matvec_ref",
            "stencil_mv_ref", "fused_step_ref", "cheb_step_ref",
@@ -16,7 +21,7 @@ def rank1_update_ref(a: jax.Array, pc: jax.Array, pr: jax.Array) -> jax.Array:
 
 def panel_update_ref(a: jax.Array, c: jax.Array, r: jax.Array) -> jax.Array:
     """a (M, N) - c (M, K) @ r (K, N)."""
-    return a - c @ r
+    return a - matmul(c, r)
 
 
 def fused_step_ref(a: jax.Array, l, last, pc: jax.Array, pr: jax.Array,
@@ -42,7 +47,7 @@ def cheb_step_ref(a: jax.Array, w: jax.Array, w_prev: jax.Array,
     Op-for-op the loop body of `estimators.chebyshev.logdet_chebyshev`
     (shifted matvec, axpy, probe dot) so f32 results are bit-identical.
     """
-    mv = (2.0 * (a @ w) - center * w) / width
+    mv = (2.0 * matmul(a, w) - center * w) / width
     w_next = 2.0 * mv - w_prev
     return w_next, (v * w_next).sum(-2)
 
@@ -54,7 +59,7 @@ def cg_step_ref(a: jax.Array, p: jax.Array, x: jax.Array, r: jax.Array,
     Op-for-op the hot half of `operators.solve.cg_solve`'s loop body,
     including the guarded 0/0 -> 0 alpha of converged columns.
     """
-    ap = a @ p
+    ap = matmul(a, p)
     den = (p * ap).sum(-2)
     tiny = jnp.finfo(den.dtype).tiny
     safe = jnp.where(jnp.abs(den) > tiny, den, 1.0)
@@ -65,7 +70,7 @@ def cg_step_ref(a: jax.Array, p: jax.Array, x: jax.Array, r: jax.Array,
 
 def matvec_ref(a: jax.Array, x: jax.Array) -> jax.Array:
     """a (M, N) @ x (N,) or (N, K)."""
-    return a @ x.astype(a.dtype)
+    return matmul(a, x.astype(a.dtype))
 
 
 def stencil_mv_ref(bands: jax.Array, x: jax.Array, *,

@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import I0
+
 __all__ = ["stencil_mv_kernel", "stencil_mv_pallas"]
 
 DEFAULT_BM = 256
@@ -65,10 +67,10 @@ def stencil_mv_pallas(bands: jax.Array, x: jax.Array, *, offsets: tuple,
         functools.partial(stencil_mv_kernel, offsets=offsets, lo=lo, bm=bm),
         grid=(m_pad // bm,),
         in_specs=[
-            pl.BlockSpec((bands.shape[0], bm), lambda i: (0, i)),
-            pl.BlockSpec((m_pad + span, k), lambda i: (0, 0)),
+            pl.BlockSpec((bands.shape[0], bm), lambda i: (I0, i)),
+            pl.BlockSpec((m_pad + span, k), lambda i: (I0, I0)),
         ],
-        out_specs=pl.BlockSpec((bm, k), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((bm, k), lambda i: (i, I0)),
         out_shape=jax.ShapeDtypeStruct((m_pad, k), bands.dtype),
         interpret=interpret,
     )(bands_p, xp)

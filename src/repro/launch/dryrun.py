@@ -153,8 +153,6 @@ def lower_cell(arch: str, shape_name: str, mesh, *, smoke: bool = False,
 
 def analyze_compiled(lowered, compiled, *, chips: int, cfg, shape, n_active):
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax 0.4.x: one dict per computation
-        cost = cost[0] if cost else {}
     mem = compiled.memory_analysis()
     flops_dev = float(cost.get("flops", 0.0))
     bytes_dev = float(cost.get("bytes accessed", 0.0))

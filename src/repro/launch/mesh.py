@@ -3,15 +3,17 @@
 Defined as FUNCTIONS (not module-level constants) so importing this module
 never touches jax device state — the dry-run must set XLA_FLAGS before the
 first jax call, and smoke tests must keep seeing the single real device.
-Mesh creation goes through `repro._compat.make_mesh` so the same code runs
-on jax versions with and without ``jax.sharding.AxisType``.
 """
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-from repro._compat import make_mesh
+
+def make_mesh(axis_shapes, axis_names) -> Mesh:
+    """`jax.make_mesh` with Auto axes (jax's default is Explicit)."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

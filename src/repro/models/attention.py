@@ -197,7 +197,7 @@ def attn_apply(p, x, cfg: ModelConfig, *, mode: str = "train",
             ck = jnp.where(slot, k.astype(cache["k"].dtype), cache["k"])
             cv = jnp.where(slot, v.astype(cache["v"].dtype), cache["v"])
         else:
-            # index dtypes must match exactly on jax 0.4.x (no int promotion)
+            # one index dtype for every start index
             pos = jnp.asarray(cache_pos)
             z = jnp.zeros((), pos.dtype)
             ck = lax.dynamic_update_slice(cache["k"], k, (z, pos, z, z))

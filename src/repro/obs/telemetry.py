@@ -98,8 +98,7 @@ def running_sem(samples: jax.Array) -> jax.Array:
 
 def flush() -> None:
     """Block until all staged debug callbacks have run."""
-    if hasattr(jax, "effects_barrier"):
-        jax.effects_barrier()
+    jax.effects_barrier()
 
 
 def drain() -> Dict[str, List[float]]:

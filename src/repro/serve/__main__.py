@@ -49,7 +49,7 @@ def _add_ladder_flags(ap: argparse.ArgumentParser) -> None:
     ap.add_argument("--max-batch", type=int, default=16)
     ap.add_argument("--method", default="auto",
                     help="default method for requests that name none")
-    ap.add_argument("--dtype", default="float64",
+    ap.add_argument("--dtype", default="float32",
                     choices=["float32", "float64"])
     ap.add_argument("--seed", type=int, default=0)
 
@@ -146,6 +146,8 @@ def main(argv=None) -> int:
     _add_ladder_flags(export)
 
     args = ap.parse_args(argv)
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     if args.dtype == "float64":
         import jax
         jax.config.update("jax_enable_x64", True)

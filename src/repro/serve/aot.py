@@ -204,10 +204,7 @@ def check_fingerprint(header: Dict[str, Any], path: str) -> None:
 
 
 def _is_tracer(x) -> bool:
-    try:
-        return isinstance(x, jax.core.Tracer)
-    except AttributeError:  # pragma: no cover
-        return False
+    return isinstance(x, jax.core.Tracer)
 
 
 def load_plan(path: str, *, validate: bool = True,

@@ -71,6 +71,7 @@ class ServeConfig:
                        of compiling (see ``python -m repro.serve export``)
     ``default_method`` method used when a request does not name one
     ``dtype``          serving dtype; requests are cast on admission
+                       (float32: the TPU's kernels have no float64)
     ``seed``           base of the per-batch estimator key sequence
     """
     buckets: Tuple[int, ...] = DEFAULT_BUCKETS
@@ -79,7 +80,7 @@ class ServeConfig:
     cache_capacity: int = 32
     plan_dir: Optional[str] = None
     default_method: str = "auto"
-    dtype: str = "float64"
+    dtype: str = "float32"
     seed: int = 0
 
     def __post_init__(self):

@@ -20,5 +20,5 @@ def rng():
 @pytest.fixture
 def mesh1():
     """Single-device 1-D mesh — exercises shard_map plumbing in-process."""
-    from repro._compat import make_mesh
+    from repro.launch.mesh import make_mesh
     return make_mesh((1,), ("rows",))

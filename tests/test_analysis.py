@@ -369,6 +369,37 @@ def test_exportable_custom_calls_policy():
                       ("exportable-custom-calls",)).ok
 
 
+def test_exportable_custom_calls_accepts_mosaic_kernels():
+    """A Pallas kernel lowered for the TPU is a `tpu_custom_call` whose
+    compiled body rides in its backend config: it ships across processes.
+    A python callback next to it still blocks the export."""
+    # recorded (trimmed) from jax.jit(rank1_update_pallas).lower(...) on a
+    # described v5e chip
+    mosaic = (
+        'module @jit_rank1_update_pallas {\n'
+        '  func.func public @main(%arg0: tensor<256x512xf32>, %arg1: '
+        'tensor<256xf32>, %arg2: tensor<512xf32>) -> (tensor<256x512xf32>) {\n'
+        '    %0 = stablehlo.broadcast_in_dim %arg1, dims = [0] : '
+        '(tensor<256xf32>) -> tensor<256x1xf32>\n'
+        '    %1 = stablehlo.broadcast_in_dim %arg2, dims = [1] : '
+        '(tensor<512xf32>) -> tensor<1x512xf32>\n'
+        '    %2 = stablehlo.custom_call @tpu_custom_call(%arg0, %0, %1) '
+        '{backend_config = "{\\"custom_call_config\\": {\\"body\\": '
+        '\\"TUzvUgFNTElSMjIuMC4wZ2l0\\"}}", kernel_name = '
+        '"rank1_update_kernel"} : (tensor<256x512xf32>, tensor<256x1xf32>, '
+        'tensor<1x512xf32>) -> tensor<256x512xf32>\n'
+        '    return %2 : tensor<256x512xf32>\n'
+        '  }\n'
+        '}\n')
+    exp = AuditContext(kind="export")
+    assert run_passes(mosaic, exp, ("exportable-custom-calls",)).ok
+    cb = mosaic.replace(
+        "    return %2",
+        "    stablehlo.custom_call @xla_python_cpu_callback(%2) : "
+        "(tensor<256x512xf32>) -> ()\n    return %2")
+    assert not run_passes(cb, exp, ("exportable-custom-calls",)).ok
+
+
 def test_run_passes_labels_and_registry():
     assert set(DEFAULT_PASS_IDS) <= set(PASSES)
     r = run_passes(DENSE_HLO, dataclasses.replace(MESH_CTX, label="lbl"),

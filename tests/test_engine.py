@@ -282,7 +282,7 @@ def test_lookahead_bit_identical_across_devices():
         """
 import sys; sys.path.insert(0, %r)
 from repro.core.engine import EngineConfig, build_mesh
-from repro._compat import make_mesh
+from repro.launch.mesh import make_mesh
 rng = np.random.default_rng(13)
 n = 48
 cases = {
@@ -320,7 +320,7 @@ def test_engine_mesh_routes_eight_devices():
 import sys; sys.path.insert(0, %r)
 import repro
 from repro.core.engine import EngineConfig, build_mesh
-from repro._compat import make_mesh
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((8,), ("rows",))
 rng = np.random.default_rng(5)
 for n in (64, 96):

@@ -133,7 +133,7 @@ def test_sharded_operator_four_devices():
     out = run_with_devices(
         """
 import sys; sys.path.insert(0, %r)
-from repro._compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.estimators import ShardedOperator, logdet_slq
 rng = np.random.default_rng(0)
 n = 96

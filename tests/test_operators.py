@@ -400,7 +400,7 @@ def test_estimators_preserve_f32_under_x64(method, kw):
 def test_sharded_operator_all_devices(rng):
     """Runs on however many devices the process sees — 1 on dev boxes, 8 in
     the CI multi-device job (XLA_FLAGS=--xla_force_host_platform_device_count=8)."""
-    from repro._compat import make_mesh
+    from repro.launch.mesh import make_mesh
     ndev = jax.device_count()
     n = 96 if 96 % ndev == 0 else 12 * ndev
     a = make_spd(n, 9)

@@ -28,7 +28,7 @@ def test_parallel_eight_devices():
         """
 import sys; sys.path.insert(0, %r)
 from repro.core import slogdet
-from repro._compat import make_mesh
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((8,), ("rows",))
 rng = np.random.default_rng(11)
 for n in (64, 100):
@@ -52,7 +52,7 @@ def test_parallel_matches_across_device_counts():
     code = """
 import sys; sys.path.insert(0, %r)
 from repro.core import slogdet
-from repro._compat import make_mesh
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((jax.device_count(),), ("rows",))
 rng = np.random.default_rng(42)
 a = rng.standard_normal((96, 96))

@@ -182,13 +182,81 @@ def test_calibration_table_is_measured_not_static():
         assert v > 0
 
 
-def _dense_est_crossover(devices, lo=32, hi=1 << 22):
+def _write_table(tmp_path, devices):
+    import json
+    path = tmp_path / "table.json"
+    path.write_text(json.dumps({"devices": devices}))
+    return path
+
+
+def test_calibration_lookup_by_device_kind(tmp_path):
+    from repro.core.calibration import (CALIBRATE_CMD, CalibrationError,
+                                        clear_calibration_cache,
+                                        current_device_kind,
+                                        load_calibration)
+    entry = dict(gemm_flops=1e14, gemm_flops_bf16=1.9e14, stream_bytes=8e11,
+                 collective_lat=None, collective_bytes=None,
+                 source="measured:tpu")
+    path = _write_table(tmp_path, {
+        "TPU v5 lite": entry,
+        "cpu": dict(entry, gemm_flops=1e11, source="measured:cpu"),
+    })
+    clear_calibration_cache()
+    tpu = load_calibration(path, device_kind="TPU v5 lite")
+    assert (tpu.gemm_flops, tpu.source) == (1e14, "measured:tpu")
+    assert tpu.gemm_rate("bf16") == 1.9e14
+    # the default key is the device this process runs on
+    assert current_device_kind() == jax.devices()[0].device_kind
+    assert load_calibration(path).gemm_flops == (
+        1e14 if current_device_kind() == "TPU v5 lite" else 1e11)
+    # a single-device entry cannot price a mesh
+    with pytest.raises(CalibrationError, match="one device"):
+        tpu.collective()
+    # an unknown kind is an error naming the calibrate command, never the
+    # static defaults
+    with pytest.raises(CalibrationError) as err:
+        load_calibration(path, device_kind="TPU v9 imaginary")
+    assert CALIBRATE_CMD in str(err.value)
+    assert "TPU v9 imaginary" in str(err.value)
+
+
+def test_calibration_static_is_explicit_opt_in(monkeypatch, tmp_path):
+    from repro.core.calibration import (STATIC_DEFAULT, CalibrationError,
+                                        clear_calibration_cache,
+                                        load_calibration)
+    monkeypatch.setenv("REPRO_CALIBRATION", "static")
+    assert load_calibration() is STATIC_DEFAULT
+    monkeypatch.setenv("REPRO_CALIBRATION",
+                       str(_write_table(tmp_path, {"other": {}})))
+    clear_calibration_cache()
+    with pytest.raises(CalibrationError, match="no entry for device kind"):
+        load_calibration()
+    monkeypatch.setenv("REPRO_CALIBRATION", str(tmp_path / "missing.json"))
+    with pytest.raises(CalibrationError, match="cannot read"):
+        load_calibration()
+
+
+def test_committed_calibration_covers_cpu_and_v5e():
+    """The committed table has one measured entry per device kind the
+    repo runs on, each with a measured bf16 GEMM rate."""
+    import json
+    from repro.core.calibration import calibration_path, load_calibration
+    table = json.loads(calibration_path().read_text())["devices"]
+    for kind in ("cpu", "TPU v5 lite"):
+        cal = load_calibration(device_kind=kind)
+        assert cal.source.startswith("measured"), (kind, cal)
+        assert cal.gemm_rate("bf16") == table[kind]["gemm_flops_bf16"] > 0
+
+
+def _dense_est_crossover(devices, cal=None, lo=32, hi=1 << 22):
     """Smallest N where the selector leaves the exact family."""
-    assert select_method(spec_with_devices(lo, devices)) == "exact"
-    assert select_method(spec_with_devices(hi, devices)) != "exact"
+    def method(n):
+        return select_method(spec_with_devices(n, devices), calibration=cal)
+    assert method(lo) == "exact"
+    assert method(hi) != "exact"
     while hi - lo > 1:
         mid = (lo + hi) // 2
-        if select_method(spec_with_devices(mid, devices)) == "exact":
+        if method(mid) == "exact":
             lo = mid
         else:
             hi = mid
@@ -215,8 +283,27 @@ def _serial_mesh_crossover(devices, lo=8, hi=1 << 22):
 def test_dense_estimator_crossover_varies_with_devices():
     """The static-FLOP model divided both sides by P, making the crossover
     device-count-invariant; the measured model's collective terms do not
-    shrink with P, so it must move."""
-    assert _dense_est_crossover(1) != _dense_est_crossover(8)
+    shrink with P, so it must move.  Priced on the committed v5e entry,
+    whose collective terms were measured over four chips."""
+    from repro.core.calibration import load_calibration
+    cal = load_calibration(device_kind="TPU v5 lite")
+    assert _dense_est_crossover(1, cal) != _dense_est_crossover(8, cal)
+
+
+def test_dense_estimator_crossover_at_exact_floor_ignores_devices():
+    """Where exact first costs more than the exact-free floor only after
+    the estimator is already cheaper at every P, the floor sets the
+    crossover and P cannot move it: the committed CPU entry's case."""
+    from repro.core.calibration import load_calibration, exact_cost
+    from repro.core.plan import _EXACT_FREE_SECONDS
+    cal = load_calibration(device_kind="cpu")
+    c1, c8 = _dense_est_crossover(1, cal), _dense_est_crossover(8, cal)
+    assert c1 == c8
+
+    def exact_t(n):
+        return min(exact_cost(n, 1, cal, update=u, itemsize=8)
+                   for u in ("rank1", "panel"))
+    assert exact_t(c1 - 1) <= _EXACT_FREE_SECONDS < exact_t(c1)
 
 
 def test_serial_mesh_crossover_varies_with_devices():
@@ -486,6 +573,26 @@ def test_plan_cache_keys_on_resolved_kernel_backend(monkeypatch):
     p2 = repro.plan((21, 21), method="exact", schedule="serial")
     assert p2.config.backend == "interpret"
     assert p2._fwd is not p1._fwd
+
+
+def test_float64_pallas_plan_refused_on_tpu(monkeypatch):
+    """Mosaic has no float64: on the TPU a float64 plan that would compile
+    Pallas kernels is refused when it is built, naming float32."""
+    from repro.kernels import ops
+    monkeypatch.delenv("REPRO_KERNEL_BACKEND", raising=False)
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    assert jax.config.jax_enable_x64
+    for kw in (dict(method="exact"), dict(method="exact", update="panel"),
+               dict(method="chebyshev")):
+        with pytest.raises(ValueError, match="float32"):
+            repro.plan((76, 76), **kw)
+        # float32 builds (the kernels compile for the chip, see
+        # test_tpu_compile.py)
+        p = repro.plan((76, 76), precision="float32", **kw)
+        assert p.spec.dtype == "float32"
+    # the xla backend has float64
+    p = repro.plan((76, 76), method="exact", backend="xla")
+    assert p.spec.dtype == "float64"
 
 
 def test_repeated_plan_calls_do_not_retrace():

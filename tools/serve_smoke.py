@@ -31,7 +31,7 @@ def main() -> int:
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.Popen(
         [sys.executable, "-m", "repro.serve", "serve", "--port", "0",
-         "--buckets", "16,32", "--max-batch", "2"],
+         "--buckets", "16,32", "--max-batch", "2", "--dtype", "float64"],
         env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
         text=True)
     try:
