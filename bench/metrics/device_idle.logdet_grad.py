@@ -1,0 +1,6 @@
+"""Device idle share of the gradient cells: 100 * (1 - busy / window),
+busy the union of device op intervals in the traced window."""
+
+
+def read(ctx):
+    return ctx.trace.idle_pct()

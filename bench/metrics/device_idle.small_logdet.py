@@ -1,0 +1,7 @@
+"""The device idle share of ``device_idle.logdet.py``, read in the small-N cells, where it
+moves ``small_logdet_s``."""
+from pathlib import Path
+
+import registry
+
+read = registry.load_module(Path(__file__).with_name("device_idle.logdet.py")).read
