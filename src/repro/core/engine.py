@@ -491,27 +491,29 @@ def apply_panel(block: jax.Array, R: jax.Array, ls: jax.Array, m0,
             blk = blk.at[:, last].set(cl)
             return blk
 
-        block = lax.fori_loop(0, K, swap_body, block)
-
-    # pivot-column block, reversed so column k corresponds to pivot k
-    pc_cols = lax.dynamic_slice(block, (0, m0 - K), (Lb, K))   # (Lb, K)
-    Pc = jnp.flip(pc_cols, axis=1)
-
-    # T[k', k] = R[k', pos(pivot k)] — unit upper-triangular in (k', k)
-    t_cols = lax.dynamic_slice(R, (0, m0 - K), (K, K))
-    T = jnp.flip(t_cols, axis=1)
+        # the scope holds the whole loop call: the copies XLA adds for
+        # the loop's carry take the while op's path, not the body's
+        with obs.stage("engine.panel_swap"):
+            block = lax.fori_loop(0, K, swap_body, block)
 
     # C @ T = Pc: substitute column by column, elementwise and in a fixed
     # order.  On the TPU, solve_triangular gives a row last-bit different
     # results in different programs, which broke the bit-identity of the
     # lookahead mesh schedule with the plain one.
-    U = jnp.triu(T, 1)
-
     def substitute(j, X):
         xj = lax.dynamic_slice_in_dim(X, j, 1, axis=1)     # final column j
         return X - xj * lax.dynamic_slice_in_dim(U, j, 1, axis=0)
 
-    C = lax.fori_loop(0, K, substitute, Pc) * row_mask[:, None]
+    with obs.stage("engine.panel_substitute"):
+        # pivot-column block, reversed so column k corresponds to pivot k
+        pc_cols = lax.dynamic_slice(block, (0, m0 - K), (Lb, K))   # (Lb, K)
+        Pc = jnp.flip(pc_cols, axis=1)
+
+        # T[k', k] = R[k', pos(pivot k)] — unit upper-triangular in (k', k)
+        t_cols = lax.dynamic_slice(R, (0, m0 - K), (K, K))
+        T = jnp.flip(t_cols, axis=1)
+        U = jnp.triu(T, 1)
+        C = lax.fori_loop(0, K, substitute, Pc) * row_mask[:, None]
 
     with obs.stage("engine.panel_apply"):
         if gemm_fn is None:
@@ -583,7 +585,8 @@ def panel_rounds_serial(buf: jax.Array, n_panels: int, k: int, *,
         b = apply_panel(b, R, ls, m0, row_mask, gemm_fn=gemm_fn,
                         fused=fused)
         # park the factorized rows back so dead region stays finite
-        b = lax.dynamic_update_slice(b, R, (t0, 0))
+        with obs.stage("engine.panel_park"):
+            b = lax.dynamic_update_slice(b, R, (t0, 0))
         return b, sign * psign, logdet + plogdet
 
     zero = buf[0, 0] * 0
@@ -667,7 +670,8 @@ def _staged_stage_rank1(buf, steps: int, use_kernel=False,
     b, s, ld = condense_steps(buf, steps, update_fn=update_fn,
                               step_fn=step_fn)
     n = buf.shape[0]
-    live = lax.slice(b, (steps, 0), (n, n - steps))
+    with obs.stage("engine.stage_shrink"):
+        live = lax.slice(b, (steps, 0), (n, n - steps))
     return live, s, ld
 
 
@@ -692,7 +696,8 @@ def _staged_stage_panel(buf, steps: int, k: int, use_kernel=False,
         b, rs, rld = condense_steps(b, rem, t0=n_panels * k,
                                     update_fn=update_fn, step_fn=step_fn)
         s, ld = s * rs, ld + rld
-    live = lax.slice(b, (steps, 0), (n, n - steps))
+    with obs.stage("engine.stage_shrink"):
+        live = lax.slice(b, (steps, 0), (n, n - steps))
     return live, s, ld
 
 

@@ -440,7 +440,7 @@ def _build_forward(spec: ProblemSpec, method: str, cfg: LogdetConfig,
 
         def fwd(a, key=None, probes=None):
             # body runs at trace time: the span measures staging cost
-            with obs.span("plan.compile", cat="trace", method=method):
+            with obs.span("plan.trace", cat="trace", method=method):
                 _mark_trace(trace_log)
                 a = jnp.asarray(a, dtype)
                 s, ld = call(a)
@@ -500,7 +500,7 @@ def _build_forward(spec: ProblemSpec, method: str, cfg: LogdetConfig,
     def fwd(a, key=None, probes=None, lmin=None, lmax=None):
         from repro import estimators as _est
         # body runs at trace time: the span measures staging cost
-        with obs.span("plan.compile", cat="trace", method=method):
+        with obs.span("plan.trace", cat="trace", method=method):
             _mark_trace(trace_log)
             a = jnp.asarray(a, dtype)
             kw = _merge_bounds(est_kw, lmin, lmax, widen=False)
@@ -639,14 +639,13 @@ class LogdetPlan:
         span = contextlib.nullcontext() if traced else \
             obs.span("plan.execute", method=self.method)
         with span:
-            if self.method in EXACT_METHODS:
-                sign, ld, sem = self._fwd(x, key=None, probes=None)
-            else:
-                sign, ld, sem = self._fwd(x, key=key, probes=probes,
-                                          lmin=lmin, lmax=lmax)
+            args = (x, key, probes, lmin, lmax)
+            sign, ld, sem = self._forward(*args) if traced else \
+                self._dispatch("fwd", self._forward, *args)
             diags = self.diagnostics
             if not traced:
-                jax.block_until_ready(ld)
+                with obs.span("plan.wait"):
+                    jax.block_until_ready(ld)
                 wall = time.perf_counter() - t0
                 conv = None
                 if tele:
@@ -661,6 +660,25 @@ class LogdetPlan:
                     obs.inc("estimator.probes", self.config.num_probes)
         return LogdetResult(sign=sign, logabsdet=ld, sem=sem,
                             method_used=self.method, diagnostics=diags)
+
+    def _forward(self, x, key, probes, lmin, lmax):
+        if self.method in EXACT_METHODS:
+            return self._fwd(x, key=None, probes=None)
+        return self._fwd(x, key=key, probes=probes, lmin=lmin, lmax=lmax)
+
+    def _dispatch(self, entry: str, fn: Callable, *args, **kwargs):
+        """``fn(*args, **kwargs)`` inside a ``plan.dispatch`` span.  The
+        plan's first dispatch of ``entry`` also nests a ``plan.compile``
+        span: tracing, lowering and compiling, or the load from the
+        persistent cache, happen there."""
+        with obs.span("plan.dispatch"):
+            done = self._cache.setdefault("dispatched", set())
+            if entry in done:
+                return fn(*args, **kwargs)
+            with obs.span("plan.compile", cat="compile", entry=entry):
+                out = fn(*args, **kwargs)
+            done.add(entry)
+            return out
 
     def slogdet(self, a=None, *, key=None, probes=None, lmin=None,
                 lmax=None):
@@ -709,10 +727,12 @@ class LogdetPlan:
                     self.spec, self.method, self.config, self.mesh,
                     self.axis_name, jnp.dtype(self.spec.dtype), self._fwd)
                 self._cache["vag"] = vag
-            (sign, ld, sem), bar, cg_iters = vag(x, key=key)
+            (sign, ld, sem), bar, cg_iters = vag(x, key=key) if traced \
+                else self._dispatch("vag", vag, x, key=key)
             diags = self.diagnostics
             if not traced:
-                jax.block_until_ready(bar)
+                with obs.span("plan.wait"):
+                    jax.block_until_ready(bar)
                 wall = time.perf_counter() - t0
                 conv = None
                 if tele:

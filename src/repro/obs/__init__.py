@@ -4,8 +4,9 @@ Zero-dependency instrumentation for the plan/engine/estimator stack.
 Three modes via ``REPRO_OBS=off|metrics|trace`` (default ``off``):
 
 ==========  ==========================================================
-``off``     no-ops everywhere; no host callbacks staged into jitted
-            code (the lowered HLO is byte-identical to uninstrumented)
+``off``     nothing recorded; no host callbacks staged into jitted
+            code (the lowered HLO is byte-identical to uninstrumented);
+            host spans reach only a running ``jax.profiler`` session
 ``metrics`` counters / gauges / histograms (plan-cache hits, retraces,
             probes used, CG iterations, ...)
 ``trace``   metrics + wall-time spans + convergence telemetry streamed
@@ -15,7 +16,7 @@ Three modes via ``REPRO_OBS=off|metrics|trace`` (default ``off``):
 
 See docs/observability.md for the full tour.  Public surface::
 
-    with obs.span("plan.build"):          # host wall-time span
+    with obs.span("plan.build"):          # host span, on the profiler too
         ...
     with obs.stage("engine.pivot"):       # jax.named_scope + trace span
         ...

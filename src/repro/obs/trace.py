@@ -4,10 +4,12 @@ Two context managers:
 
 :func:`span`
     Host-side wall-time span for code that runs eagerly (plan build,
-    plan execute, exporter flush).  Records an event into the process
-    buffer when ``REPRO_OBS=trace``; otherwise it is a shared no-op
-    object, so the disabled path is one function call and an int
-    compare.
+    plan execute, exporter flush).  Always enters
+    ``jax.profiler.TraceAnnotation``, so the span lands on the host
+    plane of a ``jax.profiler`` trace, on the device ops' clock, even
+    with obs off (about a microsecond while no profiler session is
+    active).  Records an event into the process buffer when
+    ``REPRO_OBS=trace``.
 
 :func:`stage`
     For code that runs *under a jax trace* (engine schedule stages,
@@ -70,14 +72,7 @@ def _record(name: str, cat: str, ts_us: float, dur_us: float, depth: int,
             _events.append(ev)
 
 
-@contextmanager
-def _noop() -> Iterator[None]:
-    yield
-
-
-@contextmanager
-def span(name: str, *, cat: str = "host", sync: Any = None,
-         **attrs: Any) -> Iterator[None]:
+def span(name: str, *, cat: str = "host", sync: Any = None, **attrs: Any):
     """Wall-time span around eager host code.
 
     ``sync`` — an optional value (array / pytree) passed to
@@ -87,17 +82,23 @@ def span(name: str, *, cat: str = "host", sync: Any = None,
     if not _cfg.trace_enabled():
         if sync is not None:
             jax.block_until_ready(sync)
-        yield
-        return
+        return jax.profiler.TraceAnnotation(name)
+    return _recorded(name, cat, sync, attrs)
+
+
+@contextmanager
+def _recorded(name: str, cat: str, sync: Any,
+              attrs: Dict[str, Any]) -> Iterator[None]:
     st = _stack()
     depth = len(st)
     st.append(name)
     t0 = _now_us()
     try:
-        yield
+        with jax.profiler.TraceAnnotation(name):
+            yield
+            if sync is not None:
+                jax.block_until_ready(sync)
     finally:
-        if sync is not None:
-            jax.block_until_ready(sync)
         t1 = _now_us()
         st.pop()
         _record(name, cat, t0, t1 - t0, depth, attrs or None)
