@@ -79,8 +79,7 @@ class ExactConfig:
                    (bit-identical results; see `engine.EngineConfig`).
                    Requires ``schedule`` unset (mesh resolves when a mesh
                    is present) or explicitly ``"mesh"``.
-    ``fused``    — serial/staged-only: one-pass condensation steps and a
-                   composed-permutation gather for the panel swaps
+    ``fused``    — serial/staged-only: one-pass rank-1 condensation steps
                    (bit-identical results; see `engine.EngineConfig`).
     ``precision`` — ``None`` (native) or ``"bf16"``: quantize GEMM /
                    outer-product operands to bfloat16; the buffer and

@@ -92,11 +92,9 @@ class EngineConfig:
     ``fused``     serial/staged-only: run the condensation step as ONE
                   pass over the buffer — pivot argmax + §2.4 swap + the
                   rank-1 update in a single fused kernel (the swap
-                  becomes a per-column select), and the panel schedule's
-                  K sequential swap scatters become one composed-
-                  permutation gather.  Bit-identical results (asserted
-                  in tests/test_engine.py); the mesh schedule pipelines
-                  via ``lookahead`` instead.
+                  becomes a per-column select).  Bit-identical results
+                  (asserted in tests/test_engine.py); the mesh schedule
+                  pipelines via ``lookahead`` instead.
     ``precision`` ``None`` (native) or ``"bf16"``: quantize the
                   GEMM / outer-product operands to bfloat16 while the
                   buffer and all sign/parity/log accumulators stay in
@@ -449,7 +447,7 @@ def panel_factor(panel: jax.Array, m0, *, r_pos=0, update_fn=None):
 
 
 def apply_panel(block: jax.Array, R: jax.Array, ls: jax.Array, m0,
-                row_mask: jax.Array, *, gemm_fn=None, fused: bool = False):
+                row_mask: jax.Array, *, gemm_fn=None):
     """Apply a factorized panel to a trailing row block.
 
     Args:
@@ -460,41 +458,25 @@ def apply_panel(block: jax.Array, R: jax.Array, ls: jax.Array, m0,
 
     Returns the updated block.  ``gemm_fn(block, C, R)`` may override the
     final GEMM (Pallas kernel hook); default is ``block - C @ R``.
-    ``fused=True`` replaces the K sequential swap scatters (2K passes
-    over the block) with ONE composed-permutation gather — pure data
-    movement, bit-identical, and the panel schedule's dominant traffic
-    saving (the swaps re-stream the whole trailing block per panel).
+    The panel's K column swaps (``ls[k] <-> m0-1-k``, in order) are
+    composed into one permutation of the N column indices, and the block's
+    columns move once: a fixed number of passes over the block per panel,
+    whatever K.
     """
     Lb, N = block.shape
     K = R.shape[0]
 
-    if fused:
-        # compose the K swaps on an O(N) index vector, then gather once
-        def perm_body(k, idx):
-            l = ls[k]
-            last = m0 - 1 - k
-            il = idx[l]
-            ilast = idx[last]
-            return idx.at[l].set(ilast).at[last].set(il)
+    def compose(k, idx):
+        l = ls[k]
+        last = m0 - 1 - k
+        il = idx[l]
+        ilast = idx[last]
+        return idx.at[l].set(ilast).at[last].set(il)
 
-        with obs.stage("engine.panel_swap_gather"):
-            idx = lax.fori_loop(0, K, perm_body, jnp.arange(N))
-            block = jnp.take(block, idx, axis=1)
-    else:
-        # replay the K column swaps in order: swap ls[k] <-> (m0-1-k)
-        def swap_body(k, blk):
-            l = ls[k]
-            last = m0 - 1 - k
-            cl = jnp.take(blk, l, axis=1)
-            clast = jnp.take(blk, last, axis=1)
-            blk = blk.at[:, l].set(clast)
-            blk = blk.at[:, last].set(cl)
-            return blk
-
-        # the scope holds the whole loop call: the copies XLA adds for
-        # the loop's carry take the while op's path, not the body's
-        with obs.stage("engine.panel_swap"):
-            block = lax.fori_loop(0, K, swap_body, block)
+    with obs.stage("engine.panel_swap"):
+        idx = lax.fori_loop(0, K, compose, jnp.arange(N))
+        # idx is a permutation of arange(N): no out-of-bounds fill pass
+        block = jnp.take(block, idx, axis=1, mode="clip")
 
     # C @ T = Pc: substitute column by column, elementwise and in a fixed
     # order.  On the TPU, solve_triangular gives a row last-bit different
@@ -564,7 +546,7 @@ def panel_factor_dispatch(use_kernel):
 
 def panel_rounds_serial(buf: jax.Array, n_panels: int, k: int, *,
                         q0: int = 0, gemm_fn=None, update_fn=None,
-                        factor_fn=None, fused: bool = False):
+                        factor_fn=None):
     """Run ``n_panels`` serial K-panels starting at panel offset ``q0``.
 
     The serial-schedule panel loop shared by the blocked driver and the
@@ -582,8 +564,7 @@ def panel_rounds_serial(buf: jax.Array, n_panels: int, k: int, *,
         panel = lax.dynamic_slice(b, (t0, 0), (k, n))
         R, ls, psign, plogdet = factor_fn(panel, m0, update_fn=update_fn)
         row_mask = (rows >= t0 + k).astype(b.dtype)
-        b = apply_panel(b, R, ls, m0, row_mask, gemm_fn=gemm_fn,
-                        fused=fused)
+        b = apply_panel(b, R, ls, m0, row_mask, gemm_fn=gemm_fn)
         # park the factorized rows back so dead region stays finite
         with obs.stage("engine.panel_park"):
             b = lax.dynamic_update_slice(b, R, (t0, 0))
@@ -623,7 +604,7 @@ def blocked_full(a: jax.Array, *, k: int = 32, use_kernel=False,
     n_panels = (n - 1) // k
     buf, sign, logdet = panel_rounds_serial(
         a, n_panels, k, gemm_fn=gemm_fn,
-        factor_fn=panel_factor_dispatch(use_kernel), fused=fused)
+        factor_fn=panel_factor_dispatch(use_kernel))
 
     # remainder: rank-1 steps from t0 = n_panels*k to n-2, then the 1x1 tail
     t0 = n_panels * k
@@ -686,7 +667,7 @@ def _staged_stage_panel(buf, steps: int, k: int, use_kernel=False,
     n_panels = steps // k
     b, s, ld = panel_rounds_serial(
         buf, n_panels, k, gemm_fn=gemm_fn,
-        factor_fn=panel_factor_dispatch(use_kernel), fused=fused)
+        factor_fn=panel_factor_dispatch(use_kernel))
     rem = steps - n_panels * k
     if rem > 0:
         if fused or precision is not None:

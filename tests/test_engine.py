@@ -14,7 +14,8 @@ import jax.numpy as jnp
 
 import repro
 from repro.core.engine import (
-    EngineConfig, LEGACY_ROUTES, build_mesh, build_serial, engine_slogdet,
+    EngineConfig, LEGACY_ROUTES, apply_panel, build_mesh, build_serial,
+    engine_slogdet,
 )
 
 SCHEDULES_SERIAL = ("serial", "staged")
@@ -439,3 +440,34 @@ def test_fused_stage_only_when_enabled(update):
                for f in run_passes(fused, ctxs[0], pid).errors)
     assert any(f.where == "engine.fused_step"
                for f in run_passes(plain, ctxs[1], pid).errors)
+
+
+# ------------------------------------------ the panel's column permutation
+
+# (N, m0, ls): at step k the panel swapped columns ls[k] <-> m0-1-k
+PANEL_SWAPS = {
+    "l_is_last": (12, 12, [11, 10, 9]),
+    "same_l_twice": (12, 12, [3, 5, 3]),
+    "l_on_a_moved_column": (12, 12, [2, 2, 9]),
+    "last_on_a_moved_column": (12, 12, [10, 0, 4]),
+    "k_is_one": (12, 12, [4]),
+    "m0_below_n": (12, 9, [1, 7, 1, 0]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PANEL_SWAPS))
+def test_panel_permutation_matches_sequential_swaps(case):
+    """``apply_panel`` moves the block's columns as the panel's K swaps,
+    replayed one by one, would.  With R = 0 the update adds nothing, so
+    only the move is compared, bit for bit."""
+    n, m0, ls = PANEL_SWAPS[case]
+    block = np.random.default_rng(5).standard_normal((7, n)).astype(np.float32)
+    want = block.copy()
+    for k, l in enumerate(ls):
+        last = m0 - 1 - k
+        want[:, [l, last]] = want[:, [last, l]]
+    R = jnp.zeros((len(ls), n), jnp.float32)
+    got = jax.jit(apply_panel)(
+        jnp.asarray(block), R, jnp.asarray(ls, jnp.int32), m0,
+        jnp.ones((7,), jnp.float32))
+    np.testing.assert_array_equal(np.asarray(got), want)
