@@ -24,7 +24,18 @@ implementation, parameterized on three orthogonal axes:
 
 Every combination shares exactly one implementation of pivot selection,
 §2.4 column-swap bookkeeping, sign/parity tracking, the remainder rank-1
-steps, and the P x P tail reduction (`mesh_tail`).  The legacy modules
+steps, and the P x P tail reduction (`mesh_tail`).
+
+Orientation: on one chip the panel loop (`panel_rounds_serial`, run by
+the serial blocked driver and every staged x panel stage) holds the
+condensed buffer B transposed, ``S = B^T``, and the stages hand it on
+so.  A pivot's column swap is then a swap of two contiguous rows of S,
+and a panel's K swaps move at most 2K rows (`apply_panel_rows`), where
+on a row-major B they moved the whole buffer.  The mesh shards B by
+rows, so there a column swap is local to each chip and a row swap
+would cross chips: the mesh keeps B and its column swaps
+(`apply_panel`).  Both share the panel factorization, the substitution
+and the trailing GEMM kernel; the pivots and the sign are B's on both.  The legacy modules
 (core/condense.py, core/blocked.py, core/parallel.py) are thin wrappers
 over this engine; the Gaussian-elimination and ScaLAPACK baselines stay
 separate algorithms but adopt the shared sign helpers (`perm_parity`,
@@ -53,6 +64,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import PartitionSpec
 
 from repro import obs
@@ -62,6 +74,7 @@ __all__ = [
     "EngineConfig", "LEGACY_ROUTES", "SCHEDULES", "UPDATES", "BACKENDS",
     "build_serial", "build_mesh", "engine_slogdet",
     "condense_steps", "condense_full", "panel_factor", "apply_panel",
+    "apply_panel_rows",
     "panel_rounds_serial", "mc_step_fn", "mc_local_phase", "mesh_tail",
     "combine_slogdet", "guarded_pivot", "cyclic_perm",
     "perm_parity",
@@ -446,9 +459,33 @@ def panel_factor(panel: jax.Array, m0, *, r_pos=0, update_fn=None):
     return R, ls, sign, logdet
 
 
+def _substitute(Pc: jax.Array, U: jax.Array, axis: int) -> jax.Array:
+    """Solve ``C @ (I + U) = Pc`` for C, U strictly upper triangular (K, K),
+    with the K pivots along ``axis`` of ``Pc`` (1: ``Pc`` is (Lb, K); 0:
+    it is the transposed (K, Lb)).  Column by column, elementwise and in a
+    fixed order, so both orientations give the same bits.  On the TPU,
+    solve_triangular gives a row last-bit different results in different
+    programs, which broke the bit-identity of the lookahead mesh schedule
+    with the plain one."""
+    def step(j, X):
+        xj = lax.dynamic_slice_in_dim(X, j, 1, axis=axis)
+        uj = lax.dynamic_slice_in_dim(U, j, 1, axis=0)     # (1, K)
+        return X - xj * (uj if axis == 1 else uj.T)
+
+    return lax.fori_loop(0, U.shape[0], step, Pc)
+
+
+def _pivot_triangle(R: jax.Array, m0) -> jax.Array:
+    """U = triu(T, 1), T[k', k] = R[k', pos(pivot k)]: the strictly upper
+    part of the panel's unit upper-triangular pivot block."""
+    K = R.shape[0]
+    t_cols = lax.dynamic_slice(R, (0, m0 - K), (K, K))
+    return jnp.triu(jnp.flip(t_cols, axis=1), 1)
+
+
 def apply_panel(block: jax.Array, R: jax.Array, ls: jax.Array, m0,
                 row_mask: jax.Array, *, gemm_fn=None):
-    """Apply a factorized panel to a trailing row block.
+    """Apply a factorized panel to a trailing row block (the mesh's path).
 
     Args:
       block:    (Lb, N) trailing rows (full static width).
@@ -460,8 +497,9 @@ def apply_panel(block: jax.Array, R: jax.Array, ls: jax.Array, m0,
     final GEMM (Pallas kernel hook); default is ``block - C @ R``.
     The panel's K column swaps (``ls[k] <-> m0-1-k``, in order) are
     composed into one permutation of the N column indices, and the block's
-    columns move once: a fixed number of passes over the block per panel,
-    whatever K.
+    columns move once.  The mesh shards B by rows, so a column swap stays
+    local to each chip where a row swap would cross chips; on one chip
+    `apply_panel_rows` moves rows of the transposed buffer instead.
     """
     Lb, N = block.shape
     K = R.shape[0]
@@ -478,29 +516,58 @@ def apply_panel(block: jax.Array, R: jax.Array, ls: jax.Array, m0,
         # idx is a permutation of arange(N): no out-of-bounds fill pass
         block = jnp.take(block, idx, axis=1, mode="clip")
 
-    # C @ T = Pc: substitute column by column, elementwise and in a fixed
-    # order.  On the TPU, solve_triangular gives a row last-bit different
-    # results in different programs, which broke the bit-identity of the
-    # lookahead mesh schedule with the plain one.
-    def substitute(j, X):
-        xj = lax.dynamic_slice_in_dim(X, j, 1, axis=1)     # final column j
-        return X - xj * lax.dynamic_slice_in_dim(U, j, 1, axis=0)
-
     with obs.stage("engine.panel_substitute"):
         # pivot-column block, reversed so column k corresponds to pivot k
-        pc_cols = lax.dynamic_slice(block, (0, m0 - K), (Lb, K))   # (Lb, K)
-        Pc = jnp.flip(pc_cols, axis=1)
-
-        # T[k', k] = R[k', pos(pivot k)] — unit upper-triangular in (k', k)
-        t_cols = lax.dynamic_slice(R, (0, m0 - K), (K, K))
-        T = jnp.flip(t_cols, axis=1)
-        U = jnp.triu(T, 1)
-        C = lax.fori_loop(0, K, substitute, Pc) * row_mask[:, None]
+        Pc = jnp.flip(lax.dynamic_slice(block, (0, m0 - K), (Lb, K)), axis=1)
+        C = _substitute(Pc, _pivot_triangle(R, m0), 1) * row_mask[:, None]
 
     with obs.stage("engine.panel_apply"):
         if gemm_fn is None:
             return block - matmul(C, R)
         return gemm_fn(block, C, R)
+
+
+def apply_panel_rows(s: jax.Array, R: jax.Array, ls: jax.Array, m0,
+                     col_mask: jax.Array, *, gemm_fn=None):
+    """`apply_panel` on the transposed block ``s = B^T`` (one chip's path).
+
+    Args:
+      s:        (N, Lb) the block's columns as rows (full static height).
+      R, ls, m0: as `apply_panel`.
+      col_mask: (Lb,) 1.0 for the columns of ``s`` (rows of B) that must
+                be updated, 0.0 for dead/pivot ones.
+
+    B's column swaps ``ls[k] <-> m0-1-k`` are swaps of S's rows, each two
+    contiguous rows of a row-major buffer written in place: a panel moves
+    at most 2K rows, in the swaps' own order.  B's pivot columns are then
+    S's rows ``[m0-K, m0)``; the substitution runs on them transposed,
+    with `apply_panel`'s elementwise operations in its order, and the
+    update ``S - R^T @ C^T`` goes through the same ``gemm_fn``.  Returns
+    the updated ``s``.
+    """
+    Lb = s.shape[1]
+    K = R.shape[0]
+
+    def swap(k, s):
+        l = ls[k]
+        last = m0 - 1 - k
+        row_l = lax.dynamic_slice_in_dim(s, l, 1, axis=0)
+        row_last = lax.dynamic_slice_in_dim(s, last, 1, axis=0)
+        s = lax.dynamic_update_slice_in_dim(s, row_last, l, axis=0)
+        return lax.dynamic_update_slice_in_dim(s, row_l, last, axis=0)
+
+    with obs.stage("engine.panel_swap"):
+        s = lax.fori_loop(0, K, swap, s)
+
+    with obs.stage("engine.panel_substitute"):
+        # B's pivot columns as rows, reversed so row k is pivot k's
+        Pt = jnp.flip(lax.dynamic_slice(s, (m0 - K, 0), (K, Lb)), axis=0)
+        Ct = _substitute(Pt, _pivot_triangle(R, m0), 0) * col_mask[None, :]
+
+    with obs.stage("engine.panel_apply"):
+        if gemm_fn is None:
+            return s - matmul(R.T, Ct)
+        return gemm_fn(s, R.T, Ct)
 
 
 def _kernel_request(use_kernel) -> Optional[str]:
@@ -544,34 +611,48 @@ def panel_factor_dispatch(use_kernel):
     return factor
 
 
-def panel_rounds_serial(buf: jax.Array, n_panels: int, k: int, *,
+def _row_major(x: jax.Array) -> jax.Array:
+    """``x`` held row-major: XLA then transposes the small array at hand,
+    where left free it lays out again the whole buffer ``x`` came from."""
+    return with_layout_constraint(x, Layout(major_to_minor=(0, 1)))
+
+
+def panel_rounds_serial(st: jax.Array, n_panels: int, k: int, *,
                         q0: int = 0, gemm_fn=None, update_fn=None,
                         factor_fn=None):
     """Run ``n_panels`` serial K-panels starting at panel offset ``q0``.
 
     The serial-schedule panel loop shared by the blocked driver and the
-    staged x panel stages.  Returns (buf, sign, logdet) contributions.
+    staged x panel stages.  It holds the condensed buffer B transposed,
+    ``st = B^T``: B's pivot column swaps are then swaps of contiguous
+    rows, and a panel moves at most 2K of them (`apply_panel_rows`).
+    On a row-major B a column permutation costs three passes over the
+    whole buffer: a copy into column-major, the gather and a copy back.
+    B's panel rows are S's columns ``[t0, t0+K)``, a (N, K) strip taken
+    and parked per panel.  The pivots and the sign bookkeeping are B's,
+    as with `apply_panel`.  Returns (st, sign, logdet) contributions.
     """
-    n = buf.shape[0]
-    rows = jnp.arange(n)
+    n = st.shape[0]
+    cols = jnp.arange(n)
     if factor_fn is None:
         factor_fn = panel_factor_dispatch(False)
+    obs.inc("engine.panel_exchange", axis="rows")
 
     def body(q, carry):
         b, sign, logdet = carry
         t0 = q * k
         m0 = n - t0
-        panel = lax.dynamic_slice(b, (t0, 0), (k, n))
+        panel = _row_major(lax.dynamic_slice(b, (0, t0), (n, k))).T
         R, ls, psign, plogdet = factor_fn(panel, m0, update_fn=update_fn)
-        row_mask = (rows >= t0 + k).astype(b.dtype)
-        b = apply_panel(b, R, ls, m0, row_mask, gemm_fn=gemm_fn)
+        col_mask = (cols >= t0 + k).astype(b.dtype)
+        b = apply_panel_rows(b, R, ls, m0, col_mask, gemm_fn=gemm_fn)
         # park the factorized rows back so dead region stays finite
         with obs.stage("engine.panel_park"):
-            b = lax.dynamic_update_slice(b, R, (t0, 0))
+            b = lax.dynamic_update_slice(b, R.T, (0, t0))
         return b, sign * psign, logdet + plogdet
 
-    zero = buf[0, 0] * 0
-    return lax.fori_loop(q0, q0 + n_panels, body, (buf, zero + 1, zero))
+    zero = st[0, 0] * 0
+    return lax.fori_loop(q0, q0 + n_panels, body, (st, zero + 1, zero))
 
 
 def _gemm_hook(use_kernel, precision: Optional[str]):
@@ -590,8 +671,11 @@ def blocked_full(a: jax.Array, *, k: int = 32, use_kernel=False,
                  fused: bool = False, precision: Optional[str] = None):
     """Serial blocked condensation: panels of ``k`` rows, rank-k GEMMs.
 
-    Numerically equivalent to `condense_full` up to roundoff; exercises the
-    exact panel/trailing structure used by the mesh x panel variant.
+    Numerically equivalent to `condense_full` up to roundoff.  The panels
+    run on ``a`` transposed (`panel_rounds_serial`); XLA makes the
+    transposition in the copy of ``a`` that the loop needs anyway.  The
+    rank-1 remainder then runs on the transpose of that, which XLA lays
+    out column-major as it would anyway.
     """
     n = a.shape[0]
     if a.ndim != 2 or a.shape[1] != n:
@@ -602,17 +686,14 @@ def blocked_full(a: jax.Array, *, k: int = 32, use_kernel=False,
 
     gemm_fn = _gemm_hook(use_kernel, precision)
     n_panels = (n - 1) // k
-    buf, sign, logdet = panel_rounds_serial(
-        a, n_panels, k, gemm_fn=gemm_fn,
+    st, sign, logdet = panel_rounds_serial(
+        a.T, n_panels, k, gemm_fn=gemm_fn,
         factor_fn=panel_factor_dispatch(use_kernel))
 
     # remainder: rank-1 steps from t0 = n_panels*k to n-2, then the 1x1 tail
     t0 = n_panels * k
-    if fused or precision is not None:
-        update_fn, step_fn = _step_hooks(use_kernel, fused, precision)
-    else:
-        update_fn, step_fn = None, None  # historical inline-jnp remainder
-    buf, rsign, rlogdet = condense_steps(buf, n - 1 - t0, t0=t0,
+    update_fn, step_fn = _remainder_hooks(use_kernel, fused, precision)
+    buf, rsign, rlogdet = condense_steps(st.T, n - 1 - t0, t0=t0,
                                          update_fn=update_fn,
                                          step_fn=step_fn)
     p = buf[n - 1, 0]
@@ -639,47 +720,56 @@ def stage_schedule(n: int, shrink: float, min_size: int):
     return sched
 
 
+def _remainder_hooks(use_kernel, fused: bool, precision: Optional[str]):
+    """(update_fn, step_fn) of a driver's rank-1 steps: the kernel hooks
+    for a fused or mixed-precision route, else the historical inline jnp
+    steps (None, None)."""
+    if fused or precision is not None:
+        return _step_hooks(use_kernel, fused, precision)
+    return None, None
+
+
+def _shrink_transposed(b, steps: int):
+    """The next stage's buffer, B's live block ``B[steps:, :n-steps]``,
+    transposed: the stages hand on ``B^T``."""
+    n = b.shape[0]
+    with obs.stage("engine.stage_shrink"):
+        return _row_major(lax.slice(b, (steps, 0), (n, n - steps)).T)
+
+
 @functools.partial(jax.jit, static_argnames=("steps", "use_kernel", "fused",
                                              "precision"))
-def _staged_stage_rank1(buf, steps: int, use_kernel=False,
+def _staged_stage_rank1(st, steps: int, use_kernel=False,
                         fused: bool = False,
                         precision: Optional[str] = None):
-    if fused or precision is not None:
-        update_fn, step_fn = _step_hooks(use_kernel, fused, precision)
-    else:
-        update_fn, step_fn = None, None  # historical inline-jnp stages
-    b, s, ld = condense_steps(buf, steps, update_fn=update_fn,
+    """One staged stage of ``steps`` rank-1 steps on ``st = B^T``: the steps
+    run on B = ``st.T``, which XLA lays out column-major, as ``st``."""
+    update_fn, step_fn = _remainder_hooks(use_kernel, fused, precision)
+    b, s, ld = condense_steps(st.T, steps, update_fn=update_fn,
                               step_fn=step_fn)
-    n = buf.shape[0]
-    with obs.stage("engine.stage_shrink"):
-        live = lax.slice(b, (steps, 0), (n, n - steps))
-    return live, s, ld
+    return _shrink_transposed(b, steps), s, ld
 
 
 @functools.partial(jax.jit, static_argnames=("steps", "k", "use_kernel",
                                              "fused", "precision"))
-def _staged_stage_panel(buf, steps: int, k: int, use_kernel=False,
+def _staged_stage_panel(st, steps: int, k: int, use_kernel=False,
                         fused: bool = False,
                         precision: Optional[str] = None):
-    """One staged stage eliminating ``steps`` rows via K-panels + remainder."""
+    """One staged stage eliminating ``steps`` rows via K-panels + remainder,
+    on ``st = B^T``: the panels hold it as it is (`panel_rounds_serial`),
+    the rank-1 remainder runs on ``st.T``."""
     gemm_fn = _gemm_hook(use_kernel, precision)
-    n = buf.shape[0]
     n_panels = steps // k
-    b, s, ld = panel_rounds_serial(
-        buf, n_panels, k, gemm_fn=gemm_fn,
+    st, s, ld = panel_rounds_serial(
+        st, n_panels, k, gemm_fn=gemm_fn,
         factor_fn=panel_factor_dispatch(use_kernel))
     rem = steps - n_panels * k
     if rem > 0:
-        if fused or precision is not None:
-            update_fn, step_fn = _step_hooks(use_kernel, fused, precision)
-        else:
-            update_fn, step_fn = None, None
-        b, rs, rld = condense_steps(b, rem, t0=n_panels * k,
+        update_fn, step_fn = _remainder_hooks(use_kernel, fused, precision)
+        b, rs, rld = condense_steps(st.T, rem, t0=n_panels * k,
                                     update_fn=update_fn, step_fn=step_fn)
-        s, ld = s * rs, ld + rld
-    with obs.stage("engine.stage_shrink"):
-        live = lax.slice(b, (steps, 0), (n, n - steps))
-    return live, s, ld
+        return _shrink_transposed(b, steps), s * rs, ld + rld
+    return _shrink_transposed(st.T, steps), s, ld
 
 
 def staged_full(a: jax.Array, *, shrink: float = 0.75, min_size: int = 64,
@@ -693,6 +783,16 @@ def staged_full(a: jax.Array, *, shrink: float = 0.75, min_size: int = 64,
     with shrink=0.75 at the cost of a handful of compilations.  With
     ``update="panel"`` each stage runs rank-K panels (MXU GEMMs) instead of
     rank-1 steps — the schedule x update combination no legacy route named.
+
+    The stages hold the buffer transposed, ``B^T``, and hand it on so: a
+    panel's pivot swaps then move at most 2K rows of it
+    (`panel_rounds_serial`), and the rank-1 steps run on its transpose,
+    which XLA lays out column-major as it would anyway.  ``a`` is
+    transposed once, in the copy the first stage makes of it anyway, and
+    the last live block once more for the tail.  Between two stages only
+    the live block's slice (``engine.stage_shrink``) moves the buffer.
+    The pivots are B's, so the answer is that of condensing ``a``, not
+    ``a^T``.
     """
     n = a.shape[0]
     if n <= min_size:
@@ -702,33 +802,28 @@ def staged_full(a: jax.Array, *, shrink: float = 0.75, min_size: int = 64,
         return condense_full(a, use_kernel=use_kernel, fused=fused,
                              precision=precision)
     parts = []
-    buf = a
-    for size, steps in stage_schedule(n, shrink, min_size):
-        if buf.shape[0] != size:  # defensive; schedule and buffer must agree
-            raise AssertionError((buf.shape, size))
-        if size - steps <= 1:
-            if update == "panel" and size > k:
-                parts.append(blocked_full(buf, k=k, use_kernel=use_kernel,
-                                          fused=fused, precision=precision))
-            else:
-                parts.append(condense_full(buf, use_kernel=use_kernel,
-                                           fused=fused, precision=precision))
-            buf = None
-            break
+    st = a.T
+    # every entry but the last leaves at least min_size live; the last
+    # finishes to 1 x 1
+    *stages, last = stage_schedule(n, shrink, min_size)
+    for size, steps in stages:
+        if st.shape[0] != size:  # defensive; schedule and buffer must agree
+            raise AssertionError((st.shape, size))
         if update == "panel" and steps >= k:
-            buf, s, ld = _staged_stage_panel(buf, steps, k, use_kernel,
-                                             fused, precision)
+            st, s, ld = _staged_stage_panel(st, steps, k, use_kernel,
+                                            fused, precision)
         else:
-            buf, s, ld = _staged_stage_rank1(buf, steps, use_kernel,
-                                             fused, precision)
+            st, s, ld = _staged_stage_rank1(st, steps, use_kernel,
+                                            fused, precision)
         parts.append((s, ld))
-    if buf is not None:
-        if update == "panel" and buf.shape[0] > k:
-            parts.append(blocked_full(buf, k=k, use_kernel=use_kernel,
-                                      fused=fused, precision=precision))
-        else:
-            parts.append(condense_full(buf, use_kernel=use_kernel,
-                                       fused=fused, precision=precision))
+    if st.shape[0] != last[0]:
+        raise AssertionError((st.shape, last))
+    if update == "panel" and last[0] > k:
+        parts.append(blocked_full(st.T, k=k, use_kernel=use_kernel,
+                                  fused=fused, precision=precision))
+    else:
+        parts.append(condense_full(st.T, use_kernel=use_kernel, fused=fused,
+                                   precision=precision))
     return combine_slogdet(parts)
 
 
@@ -914,6 +1009,7 @@ def _mesh_panel_kernel(axis_name: str, k: int, *, gemm_fn=None,
 
         carry = (local, zero + 1, zero)
         if n_rounds > 0:  # static: L, k known at trace time
+            obs.inc("engine.panel_exchange", axis="columns")
             carry = lax.fori_loop(0, n_rounds * P, panel_step, carry)
         local, sign, logdet = carry
 
@@ -1135,6 +1231,7 @@ def _mesh_panel_lookahead_kernel(axis_name: str, k: int, *, gemm_fn=None,
 
         sign, logdet = zero + 1, zero
         if n_panels > 0:
+            obs.inc("engine.panel_exchange", axis="columns")
             # prologue: factor + broadcast panel 0 (no trailing GEMM to
             # hide it behind yet)
             R0, ls0, psign0, plogdet0 = factor_at(local, 0)

@@ -71,5 +71,8 @@ def panel_update_pallas(a: jax.Array, c: jax.Array, r: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=out_struct((m, n), a.dtype, a),
+        # each tile is read before it is written, so the update runs in
+        # place: a loop that carries ``a`` needs no copy of it per call
+        input_output_aliases={0: 0},
         interpret=interpret,
     )(a, c, r)

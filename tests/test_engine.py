@@ -14,8 +14,8 @@ import jax.numpy as jnp
 
 import repro
 from repro.core.engine import (
-    EngineConfig, LEGACY_ROUTES, apply_panel, build_mesh, build_serial,
-    engine_slogdet,
+    EngineConfig, LEGACY_ROUTES, apply_panel, apply_panel_rows, build_mesh,
+    build_serial, engine_slogdet,
 )
 
 SCHEDULES_SERIAL = ("serial", "staged")
@@ -456,10 +456,12 @@ PANEL_SWAPS = {
 
 
 @pytest.mark.parametrize("case", sorted(PANEL_SWAPS))
-def test_panel_permutation_matches_sequential_swaps(case):
-    """``apply_panel`` moves the block's columns as the panel's K swaps,
-    replayed one by one, would.  With R = 0 the update adds nothing, so
-    only the move is compared, bit for bit."""
+@pytest.mark.parametrize("axis", ["columns", "rows"])
+def test_panel_permutation_matches_sequential_swaps(case, axis):
+    """``apply_panel`` moves the block's columns, and ``apply_panel_rows``
+    the rows of the transposed block, as the panel's K swaps of B's
+    columns, replayed one by one, would.  With R = 0 the update adds
+    nothing, so only the move is compared, bit for bit."""
     n, m0, ls = PANEL_SWAPS[case]
     block = np.random.default_rng(5).standard_normal((7, n)).astype(np.float32)
     want = block.copy()
@@ -467,7 +469,139 @@ def test_panel_permutation_matches_sequential_swaps(case):
         last = m0 - 1 - k
         want[:, [l, last]] = want[:, [last, l]]
     R = jnp.zeros((len(ls), n), jnp.float32)
-    got = jax.jit(apply_panel)(
-        jnp.asarray(block), R, jnp.asarray(ls, jnp.int32), m0,
-        jnp.ones((7,), jnp.float32))
+    ls, mask = jnp.asarray(ls, jnp.int32), jnp.ones((7,), jnp.float32)
+    if axis == "columns":
+        got = jax.jit(apply_panel)(jnp.asarray(block), R, ls, m0, mask)
+    else:
+        got = jax.jit(apply_panel_rows)(jnp.asarray(block.T), R, ls, m0,
+                                        mask).T
     np.testing.assert_array_equal(np.asarray(got), want)
+
+
+# ------------------------------------- rows of B^T against columns of B
+
+def _recording(factor, log):
+    """``factor`` that also appends each panel's pivots ``ls`` to ``log``,
+    in the order the panels run."""
+    def fn(panel, m0, r_pos=0, update_fn=None):
+        out = factor(panel, m0, r_pos=r_pos, update_fn=update_fn)
+        jax.debug.callback(lambda ls: log.append(np.asarray(ls)), out[1],
+                           ordered=True)
+        return out
+    return fn
+
+
+def _column_blocked(a, k, factor):
+    """The serial panel driver on B itself, swapping B's columns through
+    ``apply_panel`` (the mesh's apply): panels, the rank-1 remainder and
+    the 1 x 1 tail, as `blocked_full` runs them on ``B^T``."""
+    from jax import lax
+    from repro.core.engine import condense_steps
+    from repro.numerics import log_abs
+    n = a.shape[0]
+    n_panels = (n - 1) // k
+    rows = jnp.arange(n)
+
+    def body(q, carry):
+        b, sign, logdet = carry
+        t0 = q * k
+        m0 = n - t0
+        R, ls, psign, plogdet = factor(lax.dynamic_slice(b, (t0, 0), (k, n)),
+                                       m0)
+        b = apply_panel(b, R, ls, m0, (rows >= t0 + k).astype(b.dtype))
+        b = lax.dynamic_update_slice(b, R, (t0, 0))
+        return b, sign * psign, logdet + plogdet
+
+    one = jnp.ones((), a.dtype)
+    b, sign, logdet = lax.fori_loop(0, n_panels, body, (a, one, 0 * one))
+    t0 = n_panels * k
+    b, rsign, rlogdet = condense_steps(b, n - 1 - t0, t0=t0)
+    p = b[n - 1, 0]
+    return sign * rsign * jnp.sign(p), logdet + rlogdet + log_abs(p)
+
+
+def _singular(n, seed):
+    """A zero column: singular in floating point too."""
+    a = np.random.default_rng(seed).standard_normal((n, n))
+    a[:, 5] = 0.0
+    return a
+
+
+# (matrix, k): N not a multiple of k, K = 1, a singular matrix, and a
+# matrix no larger than the staged schedule's min_size
+ROW_PATH_CASES = {
+    "n_not_multiple_of_k": (lambda: np.random.default_rng(1)
+                            .standard_normal((77, 77)), 8),
+    "k_is_one": (lambda: np.random.default_rng(2)
+                 .standard_normal((23, 23)), 1),
+    "singular": (lambda: _singular(48, 3), 8),
+    "n_at_most_min_size": (lambda: np.random.default_rng(4)
+                           .standard_normal((60, 60)), 16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ROW_PATH_CASES))
+def test_row_swaps_match_column_swaps(case):
+    """The serial panel path on B^T (row swaps) picks the same panel
+    pivots as the column-swapping path on B, and gives the same sign and
+    logabsdet to roundoff; through the staged schedule too, where
+    ``N <= min_size`` hands the whole matrix to `blocked_full`."""
+    from repro.core.engine import (
+        blocked_full, panel_factor, panel_rounds_serial, staged_full)
+    make, k = ROW_PATH_CASES[case]
+    a = jnp.asarray(make())
+    n = a.shape[0]
+    col_ls, row_ls = [], []
+    s_col, ld_col = jax.jit(lambda x: _column_blocked(
+        x, k, _recording(panel_factor, col_ls)))(a)
+    jax.block_until_ready(jax.jit(lambda x: panel_rounds_serial(
+        x.T, (n - 1) // k, k,
+        factor_fn=_recording(panel_factor, row_ls)))(a))
+    assert len(row_ls) == len(col_ls) == (n - 1) // k
+    for got, want in zip(row_ls, col_ls):
+        np.testing.assert_array_equal(got, want)
+    for s, ld in (blocked_full(a, k=k),
+                  staged_full(a, update="panel", k=k, min_size=64)):
+        assert float(s) == float(s_col)
+        if case == "singular":
+            assert float(ld) == float(ld_col) == -np.inf
+        else:
+            np.testing.assert_allclose(float(ld), float(ld_col),
+                                       rtol=1e-12)
+
+
+@pytest.mark.parametrize("n", [40, 97, 160, 300])
+def test_row_swap_path_matches_float64_slogdet(n):
+    """The staged x panel route, which holds its stages transposed, against
+    float64 LAPACK on seeded matrices."""
+    from repro.core.engine import staged_full
+    a = np.random.default_rng(n).standard_normal((n, n))
+    got = staged_full(jnp.asarray(a), update="panel", k=16, min_size=24)
+    assert_matches_ref(got, a, rtol=1e-10)
+
+
+def test_panel_exchange_counts_each_panel_loop_by_axis(mesh1):
+    """``engine.panel_exchange{axis}`` counts, at trace time, one per
+    compiled panel loop: ``rows`` for the one-chip loop on B^T, ``columns``
+    for the mesh's (plain and lookahead)."""
+    from repro import obs
+    from repro.core.engine import blocked_full, staged_full
+    a = jnp.asarray(np.random.default_rng(8).standard_normal((44, 44)))
+    obs.reset()
+    obs.configure("metrics")
+    try:
+        jax.clear_caches()
+        blocked_full(a, k=4)
+        assert obs.counter_value("engine.panel_exchange", axis="rows") == 1
+        # two panel stages (44 -> 33 -> 25) and a blocked tail of 25
+        staged_full(a, update="panel", k=4, min_size=25)
+        assert obs.counter_value("engine.panel_exchange", axis="rows") == 4
+        for lookahead in (False, True):
+            build_mesh(EngineConfig(schedule="mesh", update="panel",
+                                    panel_k=4, lookahead=lookahead),
+                       mesh1)(a)
+        assert obs.counter_value("engine.panel_exchange",
+                                 axis="columns") == 2
+    finally:
+        obs.reset()
+        obs.configure("off")

@@ -1,6 +1,6 @@
 """The main path's Pallas kernels compile for a TPU v5e, at the widths the
-chip smoke (``chip_smoke.py``) runs them, and the N=8000 panel stage
-compiles without a whole-buffer move per column swap, and the exact
+chip smoke (``chip_smoke.py``) runs them, the N=8000 and N=1000 panel
+stages compile with no whole-buffer move in their panel loop, and the exact
 backward's own-LU program (``exact_pullback`` past ``XLA_LU_ROWS``) holds
 at most three matrices.
 
@@ -118,15 +118,46 @@ def _computations(text):
     return comps
 
 
-def test_panel_swap_moves_no_whole_buffer_per_swap(one_chip, monkeypatch):
-    """The first stage of the N=8000 exact logdet (staged x panel, k=64):
-    the loops under ``engine.panel_swap`` hold no copy or
-    dynamic-update-slice of the whole buffer, so each panel moves it a
-    fixed number of times, not once or twice per swap."""
+# ops a panel ran at N=1000, k=32 when the panel loop swapped B's columns
+# (one gather and two whole-buffer copies a panel), counted as `_launched`
+# counts them with ``jax_enable_x64`` on, as conftest sets it (64 with it
+# off)
+COLUMN_SWAP_PANEL_OPS = 145
+_NOT_RUN = ("parameter", "get-tuple-element", "bitcast", "tuple", "constant")
+_OP = re.compile(r"\s*(?:ROOT )?%\S+ = (\(.*?\)|\S+) ([a-z][\w-]*)\(")
+
+
+def _launched(comps, body):
+    """Ops one pass of the loop ``body`` runs: its own and, once each,
+    those of the loops and calls it holds; a fusion counts as one."""
+    ops = []
+    for line in comps[body]:
+        op = _OP.match(line)
+        if not op or op.group(2) in _NOT_RUN:
+            continue
+        ops.append(op.group(2))
+        if op.group(2) == "while":
+            ops += _launched(comps, re.search(r"body=%?([\w.\-]+)",
+                                              line).group(1))
+        elif op.group(2) == "call":
+            ops += _launched(comps, re.search(r"to_apply=%?([\w.\-]+)",
+                                              line).group(1))
+    return ops
+
+
+@pytest.mark.parametrize("n, k, steps", [(8000, 64, 2000), (1000, 32, 250)])
+def test_panel_swap_moves_no_whole_buffer_per_swap(one_chip, monkeypatch, n,
+                                                   k, steps):
+    """The first stage of the exact logdet at N=8000 (staged x panel,
+    k=64) and N=1000 (k=32): the loop that holds ``kernel.panel_update``,
+    and every computation it calls, holds no copy, transpose or gather of
+    the whole buffer.  Only the GEMM's custom-call (in place), the swaps'
+    row writes and the park write the whole-buffer shape.  At N=1000,
+    where each op's latency sets the time, a panel runs no more ops than
+    when it swapped B's columns."""
     from repro.core.engine import _staged_stage_panel
     from repro.kernels import ops
     monkeypatch.setattr(ops, "on_tpu", lambda: True)    # the chip's kernels
-    n, k, steps = 8000, 64, 2000
     text = _compile(one_chip,
                     lambda b: _staged_stage_panel(b, steps, k, "pallas"),
                     ((n, n), F32))
@@ -134,21 +165,37 @@ def test_panel_swap_moves_no_whole_buffer_per_swap(one_chip, monkeypatch):
     loops = [re.search(r"body=%?([\w.\-]+)", line).group(1)
              for lines in comps.values() for line in lines
              if re.search(r" while\(", line)
-             and "engine.panel_swap/" in line]
-    assert loops, "no loop under engine.panel_swap"
-    seen, moves = set(loops), []
-    while loops:                         # each body and what it calls
-        for line in comps[loops.pop()]:
-            op = re.match(r"\s*(?:ROOT )?%\S+ = (\S+) ([a-z][\w-]*)\(", line)
+             and any("kernel.panel_update/" in x
+                     for x in comps[re.search(r"body=%?([\w.\-]+)",
+                                              line).group(1)])]
+    assert len(loops) == 1, loops
+    body = loops[0]
+    seen, todo, writes = {body}, [body], []
+    while todo:                          # the body and what it calls
+        for line in comps[todo.pop()]:
+            op = _OP.match(line)
             if op and op.group(1).startswith(f"f32[{n},{n}]") \
-                    and op.group(2) in ("copy", "dynamic-update-slice"):
-                moves.append(line.strip()[:120])
+                    and op.group(2) not in _NOT_RUN:
+                name = re.search(r'op_name="([^"]*)"', line)
+                writes.append((op.group(2), name.group(1) if name else ""))
             for callee in re.findall(
                     r"(?:calls|body|condition|to_apply)=%?([\w.\-]+)", line):
                 if callee not in seen:
                     seen.add(callee)
-                    loops.append(callee)
-    assert moves == []
+                    todo.append(callee)
+    assert not [w for w in writes
+                if w[0] in ("copy", "transpose", "gather")], writes
+    allowed = {"custom-call": ("kernel.panel_update",),
+               "dynamic-update-slice": ("engine.panel_swap",
+                                        "engine.panel_park")}
+    for kind, name in writes:
+        assert kind in allowed and any(
+            s in name.split("/") for s in allowed[kind]), (kind, name)
+    assert "output_to_operand_aliasing" in next(
+        line for line in comps[body] if "kernel.panel_update/" in line
+        and "custom-call(" in line)
+    if n == 1000:
+        assert len(_launched(comps, body)) <= COLUMN_SWAP_PANEL_OPS
 
 
 @pytest.mark.parametrize("n", [4096, 8192])
